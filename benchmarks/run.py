@@ -168,8 +168,8 @@ def bench_buddy_allocator(quick: bool) -> list[str]:
 
 
 def bench_kernels(quick: bool) -> list[str]:
-    """Pallas kernels in interpret mode vs their jnp oracle (functional
-    parity timing on CPU; real perf target is TPU)."""
+    """Pallas kernels vs their jnp oracle: on the CPU backend the kernels
+    are interpreted (functional parity timing; real perf target is TPU)."""
     import jax.numpy as jnp
     from repro.kernels import flash_attention, moe_gating
     from repro.kernels.flash_attention.ref import attention_ref

@@ -254,18 +254,25 @@ def has_stage_bin(bins: list) -> bool:
     return any(getattr(b, "kind", None) == "stage" for b in bins)
 
 
+def _device_bins(n: int) -> list:
+    """``n`` executor bins over the devices present, round robin (bins
+    share a device only when there are fewer devices than bins)."""
+    import jax
+
+    devs = jax.devices()
+    return [devs[i % len(devs)] for i in range(n)]
+
+
 def measure(policy_name: str, shape: str, n_bins: int, workers: int,
             ) -> tuple[float, float]:
     """Execute one cell on the real executor (one JAX-device bin per
     simulated bin), fit a CostModel from the recorded trace, and return
     (measured makespan, fitted-simulator prediction) in seconds —
     the profile → fit → predict loop, inline."""
-    import jax
-
     from repro.core import Executor
     from repro.sched import TaskProfiler
 
-    bins = [jax.devices()[0]] * n_bins
+    bins = _device_bins(n_bins)
     prof = TaskProfiler()
     G = ALL_SHAPES[shape]()
     sched = get_scheduler(policy_name,
@@ -275,7 +282,7 @@ def measure(policy_name: str, shape: str, n_bins: int, workers: int,
         ex.run(G).result(timeout=600)
     fitted = CostModel.fit(prof)
     # simulate over the per-slot LABELS, not the device objects: the n
-    # bins are the same physical jax.Device, which an identity-keyed
+    # bins may share a physical jax.Device, which an identity-keyed
     # placement would collapse onto one simulated bin.  bin_key carries
     # the slot in device_labels order — the same order fit() calibrated
     # device_speed in.
@@ -299,8 +306,6 @@ def timeline_study(args, bins: list, out_path: str) -> None:
     docs/observability.md).  Additive by construction: the sweep rows
     and the ``--json`` payload never change.
     """
-    import jax
-
     from repro.core import Executor
     from repro.obs import (
         diff_timelines,
@@ -311,7 +316,7 @@ def timeline_study(args, bins: list, out_path: str) -> None:
     )
     from repro.sched import TaskProfiler
 
-    dev = [jax.devices()[0]] * len(bins)
+    dev = _device_bins(len(bins))
     prof = TaskProfiler()
     G = ALL_SHAPES["fanout"]()
     with Executor(num_workers=args.measure_workers, devices=dev,

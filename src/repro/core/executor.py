@@ -1258,7 +1258,15 @@ class Executor:
         fn = node.state["fn"]
         args = [self._convert(a) for a in node.state["args"]]
         lane = self.lanes.lane(node.device)
-        with ScopedDeviceContext(node.device):
+        with ScopedDeviceContext(node.device) as scope:
+            dev = scope.device
+            if isinstance(dev, jax.Device):
+                # an upstream kernel placed on another device: copy its
+                # result here (d2d) — jit refuses operands committed to
+                # different devices
+                args = [jax.device_put(a, dev)
+                        if isinstance(a, jax.Array) and a.devices() != {dev}
+                        else a for a in args]
             result = fn(*args)
         node.state["result"] = result
         writes = node.state.get("writes", ())

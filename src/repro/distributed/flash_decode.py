@@ -24,11 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover - newer jax
-    shard_map = jax.shard_map
-
 from .context import manual_mode
 
 _NEG = jnp.float32(-1e30)
@@ -93,7 +88,7 @@ def flash_decode_update(q, k_new, v_new, k_cache, v_cache, length, *,
             return out, kc, vc
 
     bspec = baxes if baxes else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, None, None, None), P(bspec, None, None, None),
                   P(bspec, None, None, None),
@@ -101,6 +96,6 @@ def flash_decode_update(q, k_new, v_new, k_cache, v_cache, length, *,
                   P()),
         out_specs=(P(bspec, None, None, None),
                    P(bspec, maxis, None, None), P(bspec, maxis, None, None)),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_new, v_new, k_cache, v_cache,
               jnp.asarray(length, jnp.int32))

@@ -2,8 +2,9 @@
 
 Each subpackage ships kernel.py (pl.pallas_call + explicit BlockSpec VMEM
 tiling), ops.py (jitted wrapper), and ref.py (pure-jnp oracle used by the
-allclose test sweeps).  Kernels validate under interpret=True on CPU; on
-TPU pass interpret=False.
+allclose test sweeps).  The wrappers compile the kernels for the TPU and
+interpret them on the CPU backend (``platform.interpret``), where the
+tests run them.
 """
 from .flash_attention.ops import flash_attention
 from .decode_attention.ops import decode_attention

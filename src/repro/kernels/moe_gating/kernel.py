@@ -2,13 +2,15 @@
 
 Replaces the argsort-based dispatch index build (O(T·k log T·k) with poor
 TPU mapping) by a streaming histogram: grid (n_token_blocks,) sequential,
-an (E,) running per-expert counter in VMEM scratch; each block computes
-its top-k, ranks duplicates *within the block* via a one-hot cumsum
-(block-sized, VMEM-resident), adds the running counts, and emits final
-capacity slots.  Overflowed entries (slot ≥ C) are flagged dropped —
-identical drop semantics to the sorted reference.
+a (1, E) running per-expert counter in VMEM scratch; each block computes
+its top-k, ranks duplicates *within the block* via a prefix-sum matmul
+over the tokens' multi-hot expert rows (block-sized, VMEM-resident), adds
+the running counts, and emits final capacity slots.  Overflowed entries
+(slot ≥ C) are flagged dropped — identical drop semantics to the sorted
+reference.
 
-VMEM per program ≈ tb·E·4B (one-hot) + E·4B; tb=256, E=160: 164 KiB.
+VMEM per program ≈ (k+2)·tb·E·4B (one-hots, ranks) + tb²·2B; tb=256,
+E=160, k=6: 1.4 MiB.
 """
 from __future__ import annotations
 
@@ -21,32 +23,63 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _gating_kernel(logits_ref, eid_ref, gate_ref, slot_ref, keep_ref,
-                   counts_ref, *, top_k: int, capacity: int, n_experts: int):
+                   counts_ref, *, top_k: int, capacity: int):
     t = pl.program_id(0)
 
     @pl.when(t == 0)
     def _init():
         counts_ref[...] = jnp.zeros_like(counts_ref)
 
-    logits = logits_ref[...].astype(jnp.float32)       # (tb, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, eids = jax.lax.top_k(probs, top_k)          # (tb, k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    probs = jax.nn.softmax(logits_ref[...].astype(jnp.float32), axis=-1)
+    tb, E = probs.shape
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tb, E), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (tb, top_k), 1)
+    # top-k as k rounds of max / first-argmax / mask (the order and the
+    # tie-break of lax.top_k, which Mosaic does not lower)
+    rest = probs
+    eids, gates, hots = [], [], []
+    for _ in range(top_k):
+        g = rest.max(axis=-1, keepdims=True)                        # (tb, 1)
+        e = jnp.where(rest == g, lane, E).min(axis=-1, keepdims=True)
+        hot = lane == e                                             # (tb, E)
+        rest = jnp.where(hot, -jnp.inf, rest)
+        eids.append(e)
+        gates.append(g)
+        hots.append(hot)
+    total = functools.reduce(jnp.add, gates)
+    multi = functools.reduce(jnp.add, [h.astype(jnp.float32) for h in hots])
+    # a token's k experts are distinct, so an entry's rank among same-
+    # expert entries of this block counts the earlier tokens routed
+    # there: an exclusive prefix sum over tokens, as a strictly-lower-
+    # triangular matmul (0/1 operands and counts <= tb are exact in bf16)
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (tb, tb), 0)
+           > jax.lax.broadcasted_iota(jnp.int32, (tb, tb), 1))
+    before = jax.lax.dot_general(
+        tri.astype(jnp.bfloat16), multi.astype(jnp.bfloat16),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    pos_all = before + counts_ref[...]                              # (tb, E)
 
-    flat_e = eids.reshape(-1)                          # (tb·k,) block-major
-    onehot = jax.nn.one_hot(flat_e, n_experts, dtype=jnp.int32)
-    # rank of each entry among same-expert entries within this block
-    rank = (jnp.cumsum(onehot, axis=0) - 1)[
-        jnp.arange(flat_e.shape[0]), flat_e]
-    pos = counts_ref[flat_e] + rank
-    keep = pos < capacity
-    slot = flat_e * capacity + jnp.where(keep, pos, 0)
+    eid_out = jnp.zeros((tb, top_k), jnp.int32)
+    gate_out = jnp.zeros((tb, top_k), jnp.float32)
+    slot_out = jnp.zeros((tb, top_k), jnp.int32)
+    keep_out = jnp.zeros((tb, top_k), jnp.int32)
+    for j in range(top_k):
+        pos = jnp.where(hots[j], pos_all, 0.0).sum(
+            axis=-1, keepdims=True).astype(jnp.int32)               # (tb, 1)
+        keep = pos < capacity
+        here = col == j
+        eid_out = jnp.where(here, eids[j], eid_out)
+        gate_out = jnp.where(
+            here, gates[j] / jnp.maximum(total, 1e-9), gate_out)
+        slot_out = jnp.where(
+            here, eids[j] * capacity + jnp.where(keep, pos, 0), slot_out)
+        keep_out = jnp.where(here, keep.astype(jnp.int32), keep_out)
 
-    counts_ref[...] = counts_ref[...] + onehot.sum(axis=0)
-    eid_ref[...] = eids
-    gate_ref[...] = gates.astype(gate_ref.dtype)
-    slot_ref[...] = slot.reshape(eids.shape)
-    keep_ref[...] = keep.reshape(eids.shape)
+    counts_ref[...] = counts_ref[...] + multi.sum(axis=0, keepdims=True)
+    eid_ref[...] = eid_out
+    gate_ref[...] = gate_out.astype(gate_ref.dtype)
+    slot_ref[...] = slot_out
+    keep_ref[...] = keep_out
 
 
 def moe_gating_fwd(logits, *, top_k: int, capacity: int,
@@ -69,7 +102,7 @@ def moe_gating_fwd(logits, *, top_k: int, capacity: int,
     n_t = logits.shape[0] // tb
 
     kernel = functools.partial(_gating_kernel, top_k=top_k,
-                               capacity=capacity, n_experts=E)
+                               capacity=capacity)
     eids, gates, slots, keep = pl.pallas_call(
         kernel,
         grid=(n_t,),
@@ -84,9 +117,9 @@ def moe_gating_fwd(logits, *, top_k: int, capacity: int,
             jax.ShapeDtypeStruct((n_t * tb, top_k), jnp.int32),
             jax.ShapeDtypeStruct((n_t * tb, top_k), jnp.float32),
             jax.ShapeDtypeStruct((n_t * tb, top_k), jnp.int32),
-            jax.ShapeDtypeStruct((n_t * tb, top_k), jnp.bool_),
+            jax.ShapeDtypeStruct((n_t * tb, top_k), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((E,), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((1, E), jnp.float32)],   # slots taken
         interpret=interpret,
     )(logits)
-    return eids[:T], gates[:T], slots[:T], keep[:T]
+    return eids[:T], gates[:T], slots[:T], keep[:T] != 0

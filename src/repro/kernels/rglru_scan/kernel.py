@@ -3,7 +3,8 @@
 h_t = a_t ⊙ h_{t−1} + x_t, tiled (time-chunk × channel-block).  Grid:
 (batch, n_channel_blocks, n_time_chunks) — time is innermost/sequential,
 the carry h lives in VMEM scratch between chunks.  Within a chunk the
-recurrence over `chunk` steps runs as a fori_loop on VMEM-resident tiles
+recurrence over `chunk` steps runs as a fori_loop over 16-row
+(bf16-tile-aligned) slices of the VMEM-resident tiles
 (the XLA fallback is jax.lax.associative_scan — log-depth but 2× the HBM
 traffic of this streaming form).
 
@@ -13,6 +14,7 @@ program ≈ 2·chunk·db·4B + db·4B — chunk=256, db=512: 1 MiB.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -20,27 +22,30 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _rglru_kernel(x_ref, a_ref, h0_ref, o_ref, h_ref, *, chunk: int):
+def _rglru_kernel(x_ref, a_ref, h0_ref, o_ref, h_ref, *, chunk: int,
+                  rows: int):
     t = pl.program_id(2)
 
     @pl.when(t == 0)
     def _init():
-        h_ref[...] = h0_ref[0].astype(jnp.float32)
+        h_ref[...] = h0_ref[...].astype(jnp.float32)
 
-    x = x_ref[0].astype(jnp.float32)     # (chunk, db)
-    a = a_ref[0].astype(jnp.float32)     # (chunk, db)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, h_ref.shape[1]), 0)
 
-    def step(i, carry):
-        h, out = carry
-        h = a[i] * h + x[i]
-        out = jax.lax.dynamic_update_slice(out, h[None], (i, 0))
-        return h, out
+    def step(i, h):
+        # `rows` aligned time steps per iteration, read and written
+        # through ref slices: Mosaic has no dynamic slice of a value
+        r0 = pl.multiple_of(i * rows, rows)
+        x = x_ref[pl.ds(r0, rows), :].astype(jnp.float32)   # (rows, db)
+        a = a_ref[pl.ds(r0, rows), :].astype(jnp.float32)
+        out = jnp.zeros_like(x)
+        for r in range(rows):
+            h = a[r:r + 1] * h + x[r:r + 1]
+            out = jnp.where(row == r, h, out)
+        o_ref[pl.ds(r0, rows), :] = out.astype(o_ref.dtype)
+        return h
 
-    h0 = h_ref[...]
-    out0 = jnp.zeros_like(x)
-    h, out = jax.lax.fori_loop(0, chunk, step, (h0, out0))
-    h_ref[...] = h
-    o_ref[0] = out.astype(o_ref.dtype)
+    h_ref[...] = jax.lax.fori_loop(0, chunk // rows, step, h_ref[...])
 
 
 def rglru_scan_fwd(x, a, h0, *, chunk: int = 256, channel_block: int = 512,
@@ -62,18 +67,18 @@ def rglru_scan_fwd(x, a, h0, *, chunk: int = 256, channel_block: int = 512,
     n_t = x.shape[1] // ch
     n_d = x.shape[2] // db
 
-    kernel = functools.partial(_rglru_kernel, chunk=ch)
+    kernel = functools.partial(_rglru_kernel, chunk=ch, rows=math.gcd(ch, 16))
     out = pl.pallas_call(
         kernel,
         grid=(B, n_d, n_t),
         in_specs=[
-            pl.BlockSpec((1, ch, db), lambda b, d, t: (b, t, d)),
-            pl.BlockSpec((1, ch, db), lambda b, d, t: (b, t, d)),
-            pl.BlockSpec((1, db), lambda b, d, t: (b, d)),
+            pl.BlockSpec((None, ch, db), lambda b, d, t: (b, t, d)),
+            pl.BlockSpec((None, ch, db), lambda b, d, t: (b, t, d)),
+            pl.BlockSpec((None, 1, db), lambda b, d, t: (b, 0, d)),
         ],
-        out_specs=pl.BlockSpec((1, ch, db), lambda b, d, t: (b, t, d)),
+        out_specs=pl.BlockSpec((None, ch, db), lambda b, d, t: (b, t, d)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        scratch_shapes=[pltpu.VMEM((db,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, db), jnp.float32)],
         interpret=interpret,
-    )(x, a, h0)
+    )(x, a, h0[:, None, :])
     return out[:, :S, :dr]

@@ -8,25 +8,11 @@ import to fabricate the placeholder devices.
 from __future__ import annotations
 
 import jax
-import numpy as np
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Version-tolerant mesh construction.
-
-    Newer jax: ``jax.make_mesh(..., axis_types=AxisType.Auto)``.
-    jax without ``AxisType`` (< 0.5): plain ``jax.make_mesh``.
-    jax without ``make_mesh``: reshape ``jax.devices()`` directly.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    make = getattr(jax, "make_mesh", None)
-    if make is not None and axis_type is not None:
-        return make(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    if make is not None:
-        return make(shape, axes)
-    n = int(np.prod(shape))
-    devices = np.asarray(jax.devices()[:n]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -37,6 +23,6 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _make_mesh(shape, axes)
 
 
-def make_smoke_mesh():
-    """1×1 mesh over the single real device — smoke tests / examples."""
-    return _make_mesh((1, 1), ("data", "model"))
+def make_local_mesh():
+    """(n, 1) (data, model) mesh over every device of this process."""
+    return _make_mesh((jax.device_count(), 1), ("data", "model"))

@@ -1,11 +1,11 @@
 """Production training launcher: ``--arch <id>`` over a real mesh.
 
-On hardware with >1 device this builds the production mesh and pjit's the
-train step with the DESIGN.md §6 shardings; on this CPU container it
-falls back to a single-device mesh with a reduced config (the dry-run in
-``dryrun.py`` is the at-scale proof).  The loop itself is a hetflow graph:
-host(data) → pull(batch) → kernel(step) → push(metrics), with async
-checkpoints and straggler monitoring.
+With 256 or more devices this builds the production mesh and pjit's the
+train step with its shardings; with fewer it builds a data-parallel mesh
+over every device present (one on a CPU host, four on a four-chip TPU
+host; ``dryrun.py`` is the at-scale compile proof).  The loop itself is
+a hetflow graph: host(data) → pull(batch) → kernel(step) →
+push(metrics), with async checkpoints and straggler monitoring.
 
     PYTHONPATH=src python -m repro.launch.train --arch phi3-mini-3.8b \
         --steps 20 --reduced
@@ -16,6 +16,7 @@ import argparse
 import time
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs import get_config, list_archs, reduced as reduce_cfg
 from ..core import Executor, Heteroflow
@@ -23,7 +24,8 @@ from ..data import Pipeline, PipelineConfig, SyntheticSource
 from ..distributed import named, state_pspecs, use_sharding_rules
 from ..training import (AdamWConfig, checkpoint, cosine_schedule,
                         init_train_state, make_train_step, wsd_schedule)
-from .mesh import make_production_mesh, make_smoke_mesh
+from .compile_cache import use_compile_cache
+from .mesh import make_local_mesh, make_production_mesh
 
 
 def main(argv=None) -> int:
@@ -40,6 +42,7 @@ def main(argv=None) -> int:
     p.add_argument("--multi-pod", action="store_true")
     args = p.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)
@@ -53,7 +56,7 @@ def main(argv=None) -> int:
     if n_dev >= 256:
         mesh = make_production_mesh(multi_pod=args.multi_pod)
     else:
-        mesh = make_smoke_mesh()
+        mesh = make_local_mesh()
     print(f"devices={n_dev} mesh={dict(zip(mesh.axis_names, mesh.devices.shape))}")
 
     with use_sharding_rules(mesh=mesh):
@@ -71,6 +74,10 @@ def main(argv=None) -> int:
             state, start = checkpoint.restore(
                 args.ckpt_dir, jax.eval_shape(lambda: state))
             print(f"resumed from step {start}")
+        # the state lives where the step's shardings say, the batch is
+        # split over the data axis
+        state = jax.device_put(state, sspec)
+        data = NamedSharding(mesh, P("data"))
 
         pipe = Pipeline(SyntheticSource(cfg.vocab_size),
                         PipelineConfig(batch=args.batch, seq=args.seq))
@@ -84,8 +91,8 @@ def main(argv=None) -> int:
 
         def do_step(tokens, labels):
             with jax.set_mesh(mesh):
-                new_state, metrics = jitted(
-                    box["state"], {"tokens": tokens, "labels": labels})
+                new_state, metrics = jitted(box["state"], jax.device_put(
+                    {"tokens": tokens, "labels": labels}, data))
             box["state"] = new_state
             return metrics["total_loss"]
 

@@ -26,7 +26,10 @@ Params = dict[str, Any]
 def dense_init(key, shape, dtype, scale: float | None = None):
     fan_in = shape[0] if len(shape) >= 2 else 1
     s = scale if scale is not None else 1.0 / math.sqrt(fan_in)
-    return (jax.random.normal(key, shape) * s).astype(dtype)
+    # the barrier keeps XLA from folding the scale into the sampler when
+    # init runs under jit, so jitted init draws the eager init's bits
+    z = jax.lax.optimization_barrier(jax.random.normal(key, shape))
+    return (z * s).astype(dtype)
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:

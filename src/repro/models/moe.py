@@ -130,10 +130,6 @@ def _moe_shard_map(cfg, p: Params, x, cdt, mesh, baxes, maxis):
     all-gathered over the data axis — the same bytes pjit's FSDP moves.
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax
-        shard_map = jax.shard_map
 
     m = cfg.moe
     B, S, d = x.shape
@@ -182,13 +178,13 @@ def _moe_shard_map(cfg, p: Params, x, cdt, mesh, baxes, maxis):
         return out, aux
 
     bspec = baxes if baxes else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, maxis, None), P(None, None),
                   P(maxis, bspec, None), P(maxis, bspec, None),
                   P(maxis, None, bspec)),
         out_specs=(P(bspec, maxis, None), P()),
-        check_rep=False)
+        check_vma=False)
     out, aux = fn(x, p["router"], w["w_gate"], w["w_up"], w["w_down"])
     if "shared" in p:
         # shared experts run densely in pjit land (standard dense FFN)

@@ -11,6 +11,7 @@ scan body (``jax.checkpoint``) with a configurable policy.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Any
 
 import jax
@@ -93,9 +94,31 @@ def _block_forward(cfg, mixer: str, ffn: str, p: Params, x, positions, cache):
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
+def init_layer(cfg: ModelConfig, gi: int, i: int, c: int, k_rest) -> Params:
+    """Sub-layer ``i`` of copy ``c`` in group ``gi``, drawn from
+    ``fold_in(k_rest, gi·1000 + i·100 + c)``."""
+    g = cfg.groups[gi]
+    dt = jnp.dtype(cfg.param_dtype)
+    key = jax.random.fold_in(k_rest, gi * 1000 + i * 100 + c)
+    km, kf = jax.random.split(key)
+    p = {
+        "norm1": jnp.zeros((cfg.d_model,), dt),
+        "mixer": _init_mixer(cfg, g.pattern[i], km),
+    }
+    if g.ffn_of(i) != "none":      # norm2 only exists with an FFN
+        p["norm2"] = jnp.zeros((cfg.d_model,), dt)
+    f = _init_ffn(cfg, g.ffn_of(i), kf)
+    if f:
+        p["ffn"] = f
+    return p
+
+
+@partial(jax.jit, static_argnums=0)
 def init_params(cfg: ModelConfig, key) -> Params:
-    """Concrete init.  For full configs use ``param_specs`` (eval_shape) —
-    never materialize 123B parameters on the host."""
+    """Concrete init, jitted with the config static; each group's layers
+    are built one at a time into their stacked leaves, so the device
+    holds one copy of the weights plus one layer's temporaries.  For
+    configs too large to hold use ``param_specs`` (eval_shape)."""
     dt = jnp.dtype(cfg.param_dtype)
     k_embed, k_head, k_rest = jax.random.split(key, 3)
     d = cfg.d_model
@@ -108,28 +131,12 @@ def init_params(cfg: ModelConfig, key) -> Params:
         params["lm_head"] = L.dense_init(k_head, (d, cfg.vocab_size), dt)
 
     for gi, g in enumerate(cfg.groups):
-        def init_one(i: int, mixer: str, key) -> Params:
-            km, kf = jax.random.split(key)
-            p = {
-                "norm1": jnp.zeros((d,), dt),
-                "mixer": _init_mixer(cfg, mixer, km),
-            }
-            if g.ffn_of(i) != "none":      # norm2 only exists with an FFN
-                p["norm2"] = jnp.zeros((d,), dt)
-            f = _init_ffn(cfg, g.ffn_of(i), kf)
-            if f:
-                p["ffn"] = f
-            return p
-
-        stacked = {}
-        for i, mixer in enumerate(g.pattern):
-            per_layer = [
-                init_one(i, mixer, jax.random.fold_in(k_rest, gi * 1000 + i * 100 + c))
-                for c in range(g.count)
-            ]
-            stacked[f"sub{i}"] = jax.tree.map(
-                lambda *xs: jnp.stack(xs), *per_layer)
-        params["groups"].append(stacked)
+        # a map over the copies builds one layer at a time into the stack
+        params["groups"].append({
+            f"sub{i}": jax.lax.map(
+                lambda c, i=i: init_layer(cfg, gi, i, c, k_rest),
+                jnp.arange(g.count))
+            for i in range(len(g.pattern))})
     return params
 
 

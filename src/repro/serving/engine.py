@@ -96,6 +96,11 @@ LIFECYCLE = (QUEUED, PREFILL, DECODING, DONE, PREEMPTED)
 _PREFILL_COST_PER_TOKEN = 2.0
 _DECODE_COST_PER_TOKEN = 6.0
 
+# jitted once per process with the config static, so every engine over
+# one config shares the compiled steps
+_prefill = jax.jit(transformer.prefill, static_argnums=0)
+_decode = jax.jit(transformer.decode_step, static_argnums=0)
+
 
 @dataclass(frozen=True, eq=False)
 class Request:
@@ -193,10 +198,6 @@ class ServingEngine:
         # per-slot caches (each slot = batch-1 cache ⇒ independent prefill)
         self._caches = [transformer.init_cache(cfg, 1, max_seq)
                         for _ in range(max_slots)]
-        self._prefill = jax.jit(
-            lambda p, t, c: transformer.prefill(cfg, p, t, c))
-        self._decode = jax.jit(
-            lambda p, t, c: transformer.decode_step(cfg, p, t, c))
         self._obs = obs
         #: public registry — counters/histograms the engine publishes
         #: into; :meth:`stats` is a back-compat view over it
@@ -487,8 +488,8 @@ class ServingEngine:
                     tokens = jnp.asarray(req.prompt[None, :])
                     self._caches[i] = transformer.init_cache(
                         self.cfg, 1, self.max_seq)
-                    logits, self._caches[i] = self._prefill(
-                        self.params, tokens, self._caches[i])
+                    logits, self._caches[i] = _prefill(
+                        self.cfg, self.params, tokens, self._caches[i])
                     req.generated.append(int(jnp.argmax(logits[0])))
                     now = self._clock()
                     if req.first_token_s is None:
@@ -511,8 +512,8 @@ class ServingEngine:
                 self._retire(i)
                 continue
             tok = jnp.asarray([req.generated[-1]], jnp.int32)
-            logits, self._caches[i] = self._decode(
-                self.params, tok, self._caches[i])
+            logits, self._caches[i] = _decode(
+                self.cfg, self.params, tok, self._caches[i])
             req.generated.append(int(jnp.argmax(logits[0])))
             now = self._clock()
             last = self._last_token_s.get(req.id)

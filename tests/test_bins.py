@@ -255,9 +255,9 @@ def _exec_graph(out):
 
 def _run_mixed_bins():
     import jax
-    from repro.launch.mesh import make_smoke_mesh
+    from repro.launch.mesh import make_local_mesh
 
-    mesh = make_smoke_mesh()
+    mesh = make_local_mesh()
     (mesh_bin,) = MeshBin.from_mesh(mesh)
     bins = [DeviceBin(jax.devices()[0]), HostBin(), mesh_bin]
     out = {}

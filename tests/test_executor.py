@@ -338,3 +338,44 @@ def test_replacement_moves_arena_blocks_with_the_group():
     # the stale-block bug leaves the block stranded on bin 0 instead
     assert a0.bytes_in_use == 0
     assert a1.bytes_in_use == a1.min_block        # exactly one live block
+
+
+_ACROSS_DEVICES = """
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import Executor, Heteroflow, TaskType
+
+xs = [np.full(64, i, np.float32) for i in range(8)]
+G = Heteroflow("fan_in")
+double = jax.jit(lambda x: 2.0 * x)
+parts = []
+for x in xs:
+    p = G.pull(x)
+    parts.append(G.kernel(double, p).succeed(p))
+total = G.kernel(jax.jit(lambda *a: sum(a)), *parts).succeed(*parts)
+with Executor(num_workers=4, devices=jax.devices()) as ex:
+    ex.run(G).result(timeout=60)
+homes = set()
+for n in G.nodes:
+    if n.type is TaskType.KERNEL and n is not total:
+        homes |= n.state["result"].devices()
+assert len(homes) >= 2, homes
+np.testing.assert_array_equal(np.asarray(total.result()), 2.0 * sum(xs))
+print("ok", len(homes))
+"""
+
+
+def test_kernel_operands_follow_it_across_devices():
+    """A kernel whose operands were left on other devices by upstream
+    kernels gets them copied to its own.  Four virtual CPU devices need
+    a fresh JAX, so the graph runs in a child process."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..", "src"))
+    out = subprocess.run([sys.executable, "-c", _ACROSS_DEVICES], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.startswith("ok")

@@ -1,6 +1,8 @@
 """Per-architecture smoke tests (reduced configs, assignment requirement):
 one forward/train step on CPU asserting shapes + no NaNs, plus a decode
 step — same code paths as the full configs."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +12,8 @@ from repro.configs import get_config, list_archs, reduced
 from repro.models import (decode_step, forward, init_cache, init_params,
                           loss_fn, prefill)
 from repro.models.frontends import make_patch_embeds
+from repro.models.layers import dense_init
+from repro.models.transformer import init_layer
 
 ARCHS = list_archs()
 
@@ -45,6 +49,47 @@ def test_forward_shapes_and_finiteness(rigs, arch):
     assert logits.shape == (2, S, cfg.vocab_size)
     assert np.isfinite(np.asarray(logits)).all()
     assert np.isfinite(float(aux))
+
+
+def _eager_stack_init(cfg, key):
+    """Every layer built eagerly, then each stack ``jnp.stack``-ed."""
+    dt = jnp.dtype(cfg.param_dtype)
+    k_embed, k_head, k_rest = jax.random.split(key, 3)
+    d = cfg.d_model
+    want = {"embed": dense_init(k_embed, (cfg.vocab_size, d), dt, scale=0.02),
+            "final_norm": jnp.zeros((d,), dt), "groups": []}
+    if not cfg.tie_embeddings:
+        want["lm_head"] = dense_init(k_head, (d, cfg.vocab_size), dt)
+    for gi, g in enumerate(cfg.groups):
+        want["groups"].append({
+            f"sub{i}": jax.tree.map(lambda *xs: jnp.stack(xs), *[
+                init_layer(cfg, gi, i, c, k_rest) for c in range(g.count)])
+            for i in range(len(g.pattern))})
+    return want
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_init_params_equals_eager_stack(rigs, arch):
+    """The jitted init, which maps over each stack's layers, draws the
+    bits of the eager layer-by-layer init and stack."""
+    cfg, params = rigs[arch]
+    want = _eager_stack_init(cfg, jax.random.PRNGKey(0))
+    assert jax.tree.structure(params) == jax.tree.structure(want)
+    for got, ref in zip(jax.tree.leaves(params), jax.tree.leaves(want)):
+        assert got.dtype == ref.dtype and got.shape == ref.shape
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_init_params_bf16_equals_eager_stack():
+    cfg = dataclasses.replace(reduced(get_config("phi3-mini-3.8b")),
+                              param_dtype="bfloat16")
+    key = jax.random.PRNGKey(5)
+    got = jax.tree.leaves(init_params(cfg, key))
+    want = jax.tree.leaves(_eager_stack_init(cfg, key))
+    assert {x.dtype for x in got} == {jnp.dtype(jnp.bfloat16)}
+    assert all(np.array_equal(np.asarray(a, np.float32),
+                              np.asarray(b, np.float32))
+               for a, b in zip(got, want, strict=True))
 
 
 @pytest.mark.parametrize("arch", ARCHS)

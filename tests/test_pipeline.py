@@ -102,9 +102,9 @@ def test_pipeline_over_mixed_member_stage_pool():
     """Stage slots backed by a HostBin, a DeviceBin, and a real 1x1
     MeshBin all execute correctly — stage-scope dispatch delegates to
     whatever member backs the slot."""
-    from repro.launch.mesh import make_smoke_mesh
+    from repro.launch.mesh import make_local_mesh
 
-    (mesh_bin,) = MeshBin.from_mesh(make_smoke_mesh())
+    (mesh_bin,) = MeshBin.from_mesh(make_local_mesh())
     pool = stage_bins([HostBin(), DeviceBin(jax.devices()[0]), mesh_bin])
     stages = _stages(3)
     mbs = [np.random.default_rng(i).normal(size=(4, 8)).astype(np.float32)

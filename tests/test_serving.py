@@ -1,4 +1,6 @@
 """Serving engine: continuous batching + paged arena integration."""
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -57,6 +59,25 @@ def test_engine_under_hetflow_executor(rig):
             eng.submit(np.arange(5) % cfg.vocab_size, max_new_tokens=2)
         done = eng.run()
     assert len(done) == 3
+
+
+def test_launch_serve_finishes_every_request_with_bf16_weights():
+    """The function behind ``python -m repro.launch.serve`` answers every
+    prompt, on an executor, with bf16 weights as served at full width."""
+    from repro.launch.serve import make_prompts, serve
+
+    cfg = dataclasses.replace(reduced(get_config("phi3-mini-3.8b")),
+                              param_dtype="bfloat16")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    assert {x.dtype for x in jax.tree.leaves(params)} == {
+        jax.numpy.dtype("bfloat16")}
+    prompts = make_prompts(cfg, 5)
+    assert len({len(p) for p in prompts}) == 5          # mixed lengths
+    done, stats, ex_stats = serve(cfg, params, prompts, slots=4,
+                                  max_seq=64, max_new=3)
+    assert len(done) == 5 and all(r.done for r in done)
+    assert all(len(r.generated) == 3 for r in done)
+    assert stats["completed"] == 5 and ex_stats["executed"] > 0
 
 
 @pytest.mark.slow
