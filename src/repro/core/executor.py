@@ -34,6 +34,7 @@ from typing import Any, Callable, Sequence
 import jax
 import numpy as np
 
+from ..obs.recorder import span
 from .graph import Heteroflow, KernelTask, Node, PullTask, TaskType, _span_view
 from .memory import DeviceArena, OutOfMemory
 from .placement import estimate_node_cost
@@ -41,6 +42,20 @@ from .streams import (LaneRegistry, ScopedDeviceContext, bin_labels,
                       dedup_labels, execution_target, lane_kind)
 
 __all__ = ["Executor", "Topology"]
+
+
+def _task_stats(node: Node, w: "_Worker", topo: "Topology") -> dict:
+    """Stats of an ``executor.task`` span: the task's name, the worker
+    running it, its lane and iteration, and its bin and stage where it
+    has them (the profiler would write a ``None`` as a string)."""
+    stats = {"node": node.name, "worker": w.id, "lane": lane_kind(node.type),
+             "iteration": topo.iteration}
+    if node.bin_key is not None:
+        stats["bin"] = node.bin_key
+    stage = node.state.get("stage")
+    if stage is not None:
+        stats["stage"] = stage
+    return stats
 
 
 class Topology:
@@ -176,18 +191,20 @@ class Executor:
     profiler: optional ``repro.sched.TaskProfiler``; every executed node
         is reported with wall-clock timestamps, bin label, and bytes
         moved, building the JSON trace ``CostModel.fit`` calibrates from.
-    obs: optional ``repro.obs.SpanRecorder`` flight recorder.  When set,
-        every executed node opens a span with bin/lane/node/stage
-        attribution, and the runtime's notable transitions — steals,
-        arena spills/refills, bin join/retire/fail/slowdown, straggler
-        demotions, re-placement windows, chaos triggers — land as
-        instant events in the recorder's bounded ring.  When a topology
-        fails, the ring is dumped to the recorder's ``dump_path`` (when
-        one is configured) as a Perfetto-loadable trace.  ``None``
-        (default) records nothing and adds no overhead.  Independent of
-        the recorder, scalar runtime counters live in :attr:`metrics`
-        (a ``repro.obs.MetricsRegistry``); :meth:`stats` is a
-        back-compat view over it.
+    obs: optional ``repro.obs.SpanRecorder`` flight recorder.  Every
+        executed node (or fused batch) runs inside an ``executor.task``
+        span (``repro.obs.span``: a profiler annotation, always on) with
+        node/worker/bin/lane/iteration/stage stats; when set, the span
+        also goes into the recorder's ring, and the runtime's notable
+        transitions — steals, arena spills/refills, bin
+        join/retire/fail/slowdown, straggler demotions, re-placement
+        windows, chaos triggers — land as instant events in it.  When
+        a topology fails, the ring is dumped to the recorder's
+        ``dump_path`` (when one is configured) as a Perfetto-loadable
+        trace.  ``None`` (default) writes nothing into a ring.
+        Independent of the recorder, scalar runtime counters live in
+        :attr:`metrics` (a ``repro.obs.MetricsRegistry``); :meth:`stats`
+        is a back-compat view over it.
     steal_locality: when True (default), thieves try victims whose deque
         head is placed on the same bin as the thief's last-executed
         device task before falling back to random victims — stolen work
@@ -979,28 +996,24 @@ class Executor:
             # (profiler v6 spill/refill ``span`` field): thread-local, so
             # _spill/_refill deep in the call chain can read it
             self._local.current_node = node.id
-            sid = (self._obs.begin(node.name, bin=node.bin_key,
-                                   lane=lane_kind(node.type), node=node.id,
-                                   stage=node.state.get("stage"),
-                                   worker=w.id, iteration=topo.iteration)
-                   if self._obs is not None else 0)
-            start = time.perf_counter()
-            try:
-                handler = self._VISITOR[node.type]
-                handler(self, w, node)
-            except BaseException as e:  # noqa: BLE001 — propagate via future
-                topo.failed = e
-            # injected straggling (slow_bin / chaos slow events): stretch
-            # the task by the bin's slowdown factor so telemetry — and
-            # the straggler detector reading it — sees a genuinely slow
-            # bin, closing the loop the demotion tests exercise
-            if self._slowdown and node.bin_key is not None:
-                sl = self._slowdown.get(node.bin_key)
-                if sl is not None and sl > 1.0:
-                    time.sleep((sl - 1.0) * (time.perf_counter() - start))
-            end = time.perf_counter()
-            if self._obs is not None:
-                self._obs.end(sid, ok=topo.failed is None)
+            with span("executor.task", self._obs,
+                      **_task_stats(node, w, topo)):
+                start = time.perf_counter()
+                try:
+                    handler = self._VISITOR[node.type]
+                    handler(self, w, node)
+                except BaseException as e:  # noqa: BLE001 — propagate via future
+                    topo.failed = e
+                # injected straggling (slow_bin / chaos slow events):
+                # stretch the task by the bin's slowdown factor so
+                # telemetry — and the straggler detector reading it —
+                # sees a genuinely slow bin, closing the loop the
+                # demotion tests exercise
+                if self._slowdown and node.bin_key is not None:
+                    sl = self._slowdown.get(node.bin_key)
+                    if sl is not None and sl > 1.0:
+                        time.sleep((sl - 1.0) * (time.perf_counter() - start))
+                end = time.perf_counter()
             # telemetry must not kill the worker: a raising cost_fn or
             # profiler routes into topo.failed like any task exception,
             # so the topology future still resolves
@@ -1041,29 +1054,22 @@ class Executor:
         """
         topo: Topology = batch.topology
         if topo.failed is None:
-            sid = (self._obs.begin(batch.name, bin=batch.bin_key,
-                                   lane=lane_kind(batch.type),
-                                   node=batch.id,
-                                   stage=batch.state.get("stage"),
-                                   worker=w.id, iteration=topo.iteration,
-                                   fused=len(batch.nodes))
-                   if self._obs is not None else 0)
-            start = time.perf_counter()
-            try:
-                handler = self._VISITOR[batch.type]
-                with ScopedDeviceContext(batch.device):
-                    for n in batch.nodes:
-                        self._local.current_node = n.id
-                        handler(self, w, n)
-            except BaseException as e:  # noqa: BLE001 — propagate via future
-                topo.failed = e
-            if self._slowdown and batch.bin_key is not None:
-                sl = self._slowdown.get(batch.bin_key)
-                if sl is not None and sl > 1.0:
-                    time.sleep((sl - 1.0) * (time.perf_counter() - start))
-            end = time.perf_counter()
-            if self._obs is not None:
-                self._obs.end(sid, ok=topo.failed is None)
+            with span("executor.task", self._obs,
+                      **_task_stats(batch, w, topo), fused=len(batch.nodes)):
+                start = time.perf_counter()
+                try:
+                    handler = self._VISITOR[batch.type]
+                    with ScopedDeviceContext(batch.device):
+                        for n in batch.nodes:
+                            self._local.current_node = n.id
+                            handler(self, w, n)
+                except BaseException as e:  # noqa: BLE001 — propagate via future
+                    topo.failed = e
+                if self._slowdown and batch.bin_key is not None:
+                    sl = self._slowdown.get(batch.bin_key)
+                    if sl is not None and sl > 1.0:
+                        time.sleep((sl - 1.0) * (time.perf_counter() - start))
+                end = time.perf_counter()
             try:
                 if batch.bin_key is not None:
                     w.last_bin = batch.bin_key

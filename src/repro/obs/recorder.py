@@ -1,6 +1,12 @@
-"""Lock-cheap structured span/event recorder (flight recorder).
+"""Spans and the lock-cheap flight recorder they can write into.
 
-The hot path takes no lock: every :meth:`~SpanRecorder.begin` /
+:func:`span` is how program code opens a span.  It always enters a
+``jax.profiler.TraceAnnotation``, so the span lands in a profiler trace,
+on the host clock the device's programs are shown against, whenever one
+is being taken; with a :class:`SpanRecorder` it also appends the span's
+begin/end pair to that recorder's ring.
+
+The ring's hot path takes no lock: every :meth:`~SpanRecorder.begin` /
 :meth:`~SpanRecorder.end` / :meth:`~SpanRecorder.event` call appends
 one small dict to a bounded ``collections.deque`` — atomic under
 CPython — and span ids come from ``itertools.count`` (also atomic).
@@ -13,7 +19,8 @@ Entry shape (Chrome-trace phases, so export is a straight rendering):
 * ``{"ph": "B", "span": id, "name": ..., "ts": ..., <attrs>}`` —
   span begin.  Attribution attrs (``bin``, ``lane``, ``node``,
   ``stage``, ``worker``, ...) are stored only when non-``None``.
-* ``{"ph": "E", "span": id, "ts": ...}`` — span end.
+* ``{"ph": "E", "span": id, "ts": ..., <attrs>}`` — span end, with the
+  stats a span sets on the way out (:meth:`_RecordedSpan.set_metadata`).
 * ``{"ph": "i", "name": ..., "ts": ..., <attrs>}`` — instant event.
 
 Timestamps are ``time.perf_counter()`` seconds (same clock as
@@ -25,8 +32,9 @@ from __future__ import annotations
 import itertools
 import time
 from collections import deque
-from typing import Any, Iterator
-from contextlib import contextmanager
+from typing import Any
+
+from jax.profiler import TraceAnnotation
 
 DEFAULT_CAPACITY = 65536
 
@@ -37,41 +45,23 @@ class SpanRecorder:
     ``capacity`` bounds the ring (oldest entries evicted first).
     ``dump_path``, when set, is where :meth:`on_fault` writes a
     Perfetto-loadable Chrome-trace JSON of the ring's contents.
-    ``sample_every=N`` keeps only every Nth span (see :meth:`begin`);
-    the default of 1 records everything and is byte-identical to the
-    pre-knob recorder.
     """
 
     clock = staticmethod(time.perf_counter)
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY, *,
-                 dump_path: str | None = None,
-                 sample_every: int = 1) -> None:
+                 dump_path: str | None = None) -> None:
         if capacity <= 0:
             raise ValueError(f"capacity must be positive, got {capacity}")
-        if sample_every < 1:
-            raise ValueError(
-                f"sample_every must be >= 1, got {sample_every}")
         self.capacity = capacity
         self.dump_path = dump_path
-        self.sample_every = sample_every
         self._ring: deque[dict[str, Any]] = deque(maxlen=capacity)
         self._ids = itertools.count(1)
-        self._tick = itertools.count(1)
 
     # -- recording (lock-free) -----------------------------------------
     def begin(self, name: str, *, bin: Any = None, lane: str | None = None,
               node: Any = None, stage: Any = None, **attrs: Any) -> int:
-        """Open a span; returns the span id to pass to :meth:`end`.
-
-        With ``sample_every=N`` (N > 1), only every Nth begin records a
-        span; the rest return ``0``, which :meth:`end` ignores — one
-        atomic counter bump per skipped span, the knob for 10^5+-task
-        runs where even ring appends show up.  Instant events are never
-        sampled (spills, steals, faults are rare and must survive).
-        """
-        if self.sample_every > 1 and next(self._tick) % self.sample_every:
-            return 0
+        """Open a span; returns the span id to pass to :meth:`end`."""
         sid = next(self._ids)
         e: dict[str, Any] = {"ph": "B", "span": sid, "name": name,
                              "ts": self.clock()}
@@ -80,19 +70,9 @@ class SpanRecorder:
         return sid
 
     def end(self, span: int, **attrs: Any) -> None:
-        if span <= 0:     # unsampled begin (sample_every > 1)
-            return
         e: dict[str, Any] = {"ph": "E", "span": span, "ts": self.clock()}
         _put(e, **attrs)
         self._ring.append(e)
-
-    @contextmanager
-    def span(self, name: str, **attrs: Any) -> Iterator[int]:
-        sid = self.begin(name, **attrs)
-        try:
-            yield sid
-        finally:
-            self.end(sid)
 
     def event(self, name: str, *, bin: Any = None, lane: str | None = None,
               node: Any = None, span: int | None = None,
@@ -118,9 +98,10 @@ class SpanRecorder:
     def spans(self) -> list[dict[str, Any]]:
         """Completed spans, paired from B/E entries still in the ring.
 
-        Each returned dict is the begin entry plus ``end_ts``; spans
-        whose begin fell off the ring, or that are still open, are
-        dropped (the flight recorder keeps a window, not the world).
+        Each returned dict is the begin entry, the end entry's attrs
+        and ``end_ts``; spans whose begin fell off the ring, or that are
+        still open, are dropped (the flight recorder keeps a window, not
+        the world).
         """
         open_: dict[int, dict[str, Any]] = {}
         done: list[dict[str, Any]] = []
@@ -130,7 +111,9 @@ class SpanRecorder:
             elif e["ph"] == "E":
                 b = open_.pop(e["span"], None)
                 if b is not None:
-                    done.append({**b, "end_ts": e["ts"]})
+                    done.append({**b, **{k: v for k, v in e.items()
+                                         if k not in ("ph", "span", "ts")},
+                                 "end_ts": e["ts"]})
         return done
 
     def clear(self) -> None:
@@ -166,3 +149,45 @@ def _put(e: dict[str, Any], **attrs: Any) -> None:
     for k, v in attrs.items():
         if v is not None:
             e[k] = v
+
+
+def span(name: str, recorder: SpanRecorder | None = None, **stats: Any):
+    """Context manager for one span named ``name`` with ``stats``.
+
+    The span is always a ``jax.profiler.TraceAnnotation``: free of any
+    recorder, it shows up under ``name`` with ``stats`` as its event
+    stats in a profiler trace whenever one is being taken (about a
+    microsecond when none is).  With ``recorder`` its begin/end pair
+    also goes into that ring.  Stats known only at the end go through
+    ``set_metadata(**stats)`` on the entered object.  Values are
+    strings, numbers or booleans (the profiler writes ``None`` as the
+    string ``"None"``).
+    """
+    ann = TraceAnnotation(name, **stats)
+    return ann if recorder is None else _RecordedSpan(ann, recorder, name,
+                                                      stats)
+
+
+class _RecordedSpan:
+    """A profiler annotation that also writes its begin/end pair into a
+    :class:`SpanRecorder`'s ring."""
+
+    __slots__ = ("_ann", "_rec", "_name", "_stats", "_end", "_sid")
+
+    def __init__(self, ann: TraceAnnotation, rec: SpanRecorder, name: str,
+                 stats: dict[str, Any]) -> None:
+        self._ann, self._rec, self._name, self._stats = ann, rec, name, stats
+        self._end: dict[str, Any] = {}
+
+    def __enter__(self) -> "_RecordedSpan":
+        self._ann.__enter__()
+        self._sid = self._rec.begin(self._name, **self._stats)
+        return self
+
+    def set_metadata(self, **stats: Any) -> None:
+        self._ann.set_metadata(**stats)
+        self._end.update(stats)
+
+    def __exit__(self, *exc: Any) -> None:
+        self._rec.end(self._sid, **self._end)
+        self._ann.__exit__(*exc)

@@ -37,6 +37,16 @@ latency — and :meth:`stats` is a back-compat view over it.  Pass
 preemptions, KV migrations, and bin join/retire/fail on the same
 timeline as the executor's spans.
 
+**Spans**: every tick opens ``repro.obs.span``s, always on, that land
+in a ``jax.profiler`` trace (and in the ``obs=`` ring when given):
+``engine.tick`` (stats ``active``, ``queued``, set at its end) holds
+``engine.schedule`` (``event``, ``request``, ``nodes``) around each call
+into placement, ``engine.prefill`` (``request``, ``tokens``) and
+``engine.decode`` (``request``, ``slot``) around each dispatch of the
+model, and ``engine.read`` (``request``) around each host read of a
+token.  The names are a contract with the benchmark's readers
+(docs/observability.md).
+
 KV capacity is governed per bin by the :class:`PagedKVArena` buddy pool —
 a request is admitted only when its bin's arena can host its page run
 (otherwise it queues), the vLLM admission rule built on the paper's
@@ -73,7 +83,7 @@ from ..configs.base import ModelConfig
 from ..core import Executor, Heteroflow
 from ..core.memory import OutOfMemory
 from ..models import transformer
-from ..obs import MetricsRegistry
+from ..obs import MetricsRegistry, span
 from ..sched import (
     CostModel,
     Scheduler,
@@ -348,8 +358,10 @@ class ServingEngine:
         dead_idx = {i for i in state.live
                     if state.bins[i] in failed or i in failed}
         n_pages = self.max_slots * -(-self.max_seq // self.page_tokens)
-        delta = self.scheduler.update(
-            state, SchedulerUpdate(new_bins=new, retired_bins=gone))
+        with span("engine.schedule", self._obs, event="bins",
+                  nodes=len(self._trace.nodes)):
+            delta = self.scheduler.update(
+                state, SchedulerUpdate(new_bins=new, retired_bins=gone))
         for i in state.live:
             if i not in self._arenas:
                 self._arenas[i] = self._new_arena(n_pages)
@@ -435,9 +447,11 @@ class ServingEngine:
         re-place (and double-account) on retry."""
         if req.id in self._placed:
             return self._placed[req.id]
-        pre, dec = self._request_groups(req)
-        delta = self.scheduler.update(
-            self._sched_state, SchedulerUpdate(new_tasks=(pre, dec)))
+        with span("engine.schedule", self._obs, event="admit",
+                  request=req.id, nodes=len(self._trace.nodes)):
+            pre, dec = self._request_groups(req)
+            delta = self.scheduler.update(
+                self._sched_state, SchedulerUpdate(new_tasks=(pre, dec)))
         live = sorted(self._sched_state.live)
         home = delta.get(pre.root, live[0])
         dbin = delta.get(dec.root, home)
@@ -446,6 +460,14 @@ class ServingEngine:
 
     def _tick(self) -> bool:
         """One engine iteration: admit → prefill news → decode actives."""
+        with span("engine.tick", self._obs) as tick:
+            more = self._tick_body()
+            tick.set_metadata(
+                active=sum(r is not None for r in self._slots),
+                queued=len(self._queue))
+        return more
+
+    def _tick_body(self) -> bool:
         self._ticks.inc()
         self._apply_bin_events()
         # 1. admission (scheduler-placed, arena-gated)
@@ -485,12 +507,14 @@ class ServingEngine:
                     del self._placed[req.id]
                     req._advance(state=PREFILL)
                     # prefill this slot
-                    tokens = jnp.asarray(req.prompt[None, :])
-                    self._caches[i] = transformer.init_cache(
-                        self.cfg, 1, self.max_seq)
-                    logits, self._caches[i] = _prefill(
-                        self.cfg, self.params, tokens, self._caches[i])
-                    req.generated.append(int(jnp.argmax(logits[0])))
+                    with span("engine.prefill", self._obs, request=req.id,
+                              tokens=len(req.prompt)):
+                        tokens = jnp.asarray(req.prompt[None, :])
+                        self._caches[i] = transformer.init_cache(
+                            self.cfg, 1, self.max_seq)
+                        logits, self._caches[i] = _prefill(
+                            self.cfg, self.params, tokens, self._caches[i])
+                    req.generated.append(self._read(req, logits))
                     now = self._clock()
                     if req.first_token_s is None:
                         self._ttft.observe(now - req.arrival_s)
@@ -511,10 +535,11 @@ class ServingEngine:
             if len(req.generated) >= req.max_new_tokens:
                 self._retire(i)
                 continue
-            tok = jnp.asarray([req.generated[-1]], jnp.int32)
-            logits, self._caches[i] = _decode(
-                self.cfg, self.params, tok, self._caches[i])
-            req.generated.append(int(jnp.argmax(logits[0])))
+            with span("engine.decode", self._obs, request=req.id, slot=i):
+                tok = jnp.asarray([req.generated[-1]], jnp.int32)
+                logits, self._caches[i] = _decode(
+                    self.cfg, self.params, tok, self._caches[i])
+            req.generated.append(self._read(req, logits))
             now = self._clock()
             last = self._last_token_s.get(req.id)
             if last is not None:
@@ -525,6 +550,12 @@ class ServingEngine:
             if len(req.generated) >= req.max_new_tokens:
                 self._retire(i)
         return self._has_work()
+
+    def _read(self, req: Request, logits) -> int:
+        """The greedy token, read back to the host (waits for the
+        model call that produced ``logits``)."""
+        with span("engine.read", self._obs, request=req.id):
+            return int(jnp.argmax(logits[0]))
 
     def _grow(self, req: Request) -> bool:
         """Extend ``req``'s page run, preempting the youngest *other*
@@ -592,9 +623,11 @@ class ServingEngine:
         books (``new_finished_tasks``); re-admission files fresh ones."""
         groups = self._req_groups.pop(req.id, ())
         if groups:
-            self.scheduler.update(
-                self._sched_state,
-                SchedulerUpdate(new_finished_tasks=tuple(groups)))
+            with span("engine.schedule", self._obs, event="finish",
+                      request=req.id, nodes=len(self._trace.nodes)):
+                self.scheduler.update(
+                    self._sched_state,
+                    SchedulerUpdate(new_finished_tasks=tuple(groups)))
 
     def _retire(self, slot: int) -> None:
         with self._lock:

@@ -19,6 +19,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
                                 "benchmarks"))
 
 from workloads import build_fanout  # noqa: E402
+from _profiled import profiled  # noqa: E402
 
 from repro.core import Executor, Heteroflow  # noqa: E402
 from repro.obs import (  # noqa: E402
@@ -32,6 +33,7 @@ from repro.obs import (  # noqa: E402
     save_timeline,
     timeline_from_recorder,
     timeline_from_schedule,
+    span,
     timeline_from_trace,
     validate_timeline,
 )
@@ -95,7 +97,7 @@ def test_recorder_spans_pair_and_open_spans_drop():
                     worker=0)
     rec.end(sid, ok=True)
     rec.begin("never_closed", bin="d1")
-    with rec.span("ctx", bin="d0", lane="copy"):
+    with span("ctx", rec, bin="d0", lane="copy"):
         pass
     spans = rec.spans()
     assert [s["name"] for s in spans] == ["work", "ctx"]
@@ -115,7 +117,7 @@ def test_recorder_spans_pair_and_open_spans_drop():
 def test_recorder_fault_dump_writes_valid_timeline(tmp_path):
     path = str(tmp_path / "flight.json")
     rec = SpanRecorder(dump_path=path)
-    with rec.span("doomed", bin="d0", lane="compute"):
+    with span("doomed", rec, bin="d0", lane="compute"):
         pass
     out = rec.on_fault(RuntimeError("boom"), topology=1)
     assert out == path
@@ -385,53 +387,57 @@ def test_executor_without_obs_matches_with_obs():
     assert ex1.stats()["executed"] == ex2.stats()["executed"]
 
 
-def test_recorder_sample_every_thins_spans_not_events():
-    """sample_every=N keeps every Nth span (unsampled begins return 0,
-    end ignores them) but never drops instant events — spills and
-    faults are rare and must survive the thinning."""
-    r = SpanRecorder(sample_every=4)
-    sids = [r.begin("task") for _ in range(16)]
-    for s in sids:
-        r.end(s)
-    assert sum(1 for s in sids if s) == 4
-    assert len(r.spans()) == 4
-    for _ in range(5):
-        r.event("spill")
-    assert len(r.events("spill")) == 5
-    with r.span("ctx") as sid:      # context manager tolerates sid 0
-        pass
-    with pytest.raises(ValueError):
-        SpanRecorder(sample_every=0)
+# ----------------------------------------------------------------------
+# obs.span: a profiler annotation, also written into a ring when given
+# ----------------------------------------------------------------------
+def test_span_lands_in_profiler_trace_with_stats(tmp_path):
+    def work():
+        with span("test.outer", slot=3, request=0) as outer:
+            with span("test.inner", node="k1", worker=1):
+                pass
+            outer.set_metadata(active=2)
+
+    _, got = profiled(work, tmp_path)
+    assert [s.name for s in got] == ["test.outer", "test.inner"]
+    outer, inner = got
+    assert outer.stats == {"slot": 3, "request": 0, "active": 2}
+    assert inner.stats == {"node": "k1", "worker": 1}
+    assert outer.holds(inner)
 
 
-def test_recorder_sample_every_default_records_everything():
-    r = SpanRecorder()
-    sids = [r.begin("task") for _ in range(8)]
-    for s in sids:
-        r.end(s)
-    assert all(sids) and len(r.spans()) == 8
+def test_span_writes_ring_only_when_recorder_given(tmp_path):
+    rec, idle = SpanRecorder(), SpanRecorder()
+
+    def work():
+        with span("test.bare", request=1):
+            pass
+        with span("test.ringed", rec, request=2) as s:
+            s.set_metadata(active=4)
+
+    _, got = profiled(work, tmp_path)
+    assert [(s.name, s.stats) for s in got] == [
+        ("test.bare", {"request": 1}),
+        ("test.ringed", {"request": 2, "active": 4})]
+    spans = rec.spans()
+    assert [(s["name"], s["request"], s["active"]) for s in spans] == [
+        ("test.ringed", 2, 4)]
+    assert spans[0]["end_ts"] >= spans[0]["ts"]
+    assert len(idle) == 0 and len(rec) == 2          # one B/E pair
 
 
-def test_histogram_sample_every_thins_observations():
-    h = Histogram("lat", sample_every=3)
-    for i in range(9):
-        h.observe(float(i))
-    assert h.seen == 9
-    assert h.samples == [2.0, 5.0, 8.0]    # every 3rd kept
-    h2 = Histogram("lat2", sample_every=3)
-    h2.extend(float(i) for i in range(9))
-    assert (h2.samples, h2.seen) == (h.samples, h.seen)
-    with pytest.raises(ValueError):
-        Histogram("bad", sample_every=0)
-
-
-def test_registry_sample_every_is_histogram_default():
-    reg = MetricsRegistry(sample_every=5)
-    assert reg.histogram("a").sample_every == 5
-    assert reg.histogram("b", sample_every=1).sample_every == 1
-    # counters/gauges are never sampled; default registry keeps all
-    reg0 = MetricsRegistry()
-    h = reg0.histogram("c")
-    h.extend([1.0, 2.0])
-    assert h.sample_every == 1 and h.count == h.seen == 2
-    assert h.summary()["count"] == 2
+@pytest.mark.parametrize("with_obs", [False, True],
+                         ids=["no_recorder", "recorder"])
+def test_executor_task_spans_reach_profiler(tmp_path, with_obs):
+    """Every task runs inside one ``executor.task`` span naming the task
+    and its worker, whether or not a recorder is attached; with one, the
+    ring holds the same spans."""
+    rec = SpanRecorder() if with_obs else None
+    (G, _), got = profiled(lambda: _live_run(obs=rec), tmp_path)
+    tasks = [s for s in got if s.name == "executor.task"]
+    names = sorted(n.name for n in G.nodes)
+    assert sorted(s.stats["node"] for s in tasks) == names
+    assert all(s.stats["worker"] in (0, 1) for s in tasks)
+    assert {s.stats["lane"] for s in tasks} <= {"copy", "compute", "host"}
+    if with_obs:
+        ring = [s for s in rec.spans() if s["name"] == "executor.task"]
+        assert sorted(s["node"] for s in ring) == names

@@ -4,6 +4,7 @@ import dataclasses
 import jax
 import numpy as np
 import pytest
+from _profiled import profiled
 
 from repro.configs import get_config, reduced
 from repro.core import Executor
@@ -281,3 +282,115 @@ def test_engine_add_and_retire_bin(rig):
     assert eng.stats()["bins"] == 1
     assert eng.stats()["completed"] == 1
     assert eng.arena.pages_in_use == 0
+
+
+# ----------------------------------------------------------------------
+# engine spans (repro.obs.span): names and stats are a contract with the
+# benchmark's readers (bench/common/spans.py)
+# ----------------------------------------------------------------------
+def test_engine_spans_nest_in_ticks(rig, tmp_path):
+    """One ``engine.tick`` per step; every other engine span lies inside
+    a tick and names its request; placement is entered once per
+    admission and once per retirement."""
+    cfg, params = rig
+    eng = ServingEngine(cfg, params, max_slots=2, max_seq=64)
+    asks = ((5, 3), (6, 2), (5, 4))               # (prompt, new tokens)
+    ids = [eng.submit(np.arange(n) % cfg.vocab_size, max_new_tokens=k)
+           for n, k in asks]
+
+    def drive():
+        steps = 1
+        while eng.step():
+            steps += 1
+        return steps
+
+    steps, got = profiled(drive, tmp_path)
+
+    def named(name):
+        return [s for s in got if s.name == name]
+
+    ticks = named("engine.tick")
+    assert len(ticks) == steps == eng.ticks
+    # seated at the tick's end: the two-token request retired in it
+    assert (ticks[0].stats["active"], ticks[0].stats["queued"]) == (1, 1)
+    assert (ticks[-1].stats["active"], ticks[-1].stats["queued"]) == (0, 0)
+    inner = [s for s in got if s.name.startswith("engine.")
+             and s.name != "engine.tick"]
+    assert all(any(t.holds(s) for t in ticks) for s in inner)
+    assert all(s.stats["request"] in ids for s in inner
+               if s.name != "engine.schedule")
+
+    assert eng.preemptions == 0
+    sched = named("engine.schedule")
+    assert sorted(s.stats["event"] for s in sched) == ["admit"] * 3 + ["finish"] * 3
+    assert sorted(s.stats["request"] for s in sched) == sorted(ids * 2)
+    admits = [s.stats["nodes"] for s in sched if s.stats["event"] == "admit"]
+    assert admits == sorted(admits) and len(set(admits)) == 3   # A3 growth
+
+    prefills = named("engine.prefill")
+    assert sorted((s.stats["request"], s.stats["tokens"]) for s in prefills) \
+        == sorted(zip(ids, (n for n, _ in asks)))
+    decodes, reads = named("engine.decode"), named("engine.read")
+    assert len(decodes) == sum(k - 1 for _, k in asks)
+    assert len(reads) == len(prefills) + len(decodes)
+    assert all(s.stats["slot"] in (0, 1) for s in decodes)
+    for d in decodes:                              # its token's read follows
+        r = next(r for r in reads if r.start_ns >= d.end_ns)
+        assert r.stats["request"] == d.stats["request"]
+
+
+def test_engine_spans_reach_flight_recorder(rig):
+    """With ``obs=``, the engine's spans also land in the ring, so a
+    fault dump shows them."""
+    from repro.obs import SpanRecorder, timeline_from_recorder, validate_timeline
+
+    cfg, params = rig
+    rec = SpanRecorder()
+    eng = ServingEngine(cfg, params, max_slots=1, max_seq=64, obs=rec)
+    rid = eng.submit(np.arange(5) % cfg.vocab_size, max_new_tokens=3)
+    eng.run()
+    spans = rec.spans()
+    assert [s["name"] for s in spans].count("engine.tick") == eng.ticks
+    assert {s["name"] for s in spans} == {
+        "engine.tick", "engine.schedule", "engine.prefill", "engine.decode",
+        "engine.read"}
+    assert all(s["request"] == rid for s in spans if "request" in s)
+    assert validate_timeline(timeline_from_recorder(rec)) == []
+
+
+@pytest.mark.parametrize("program,reader", [
+    ("_prefill", "chat.prefill_ms"),
+    ("_decode", "batch.decode_roofline"),
+])
+def test_engine_program_names_match_bench_readers(rig, program, reader):
+    """The benchmark finds the engine's compiled programs by name in the
+    device trace (``jit_prefill``, ``jit_decode_step``): a rename must
+    fail here rather than silence its reader."""
+    import json
+    import jax.numpy as jnp
+    from pathlib import Path
+
+    from bench.common import peaks
+    from bench.common.harness import Readings, load_module
+    from bench.common.trace import Trace
+    from repro.models import init_cache
+    from repro.serving import engine
+
+    cfg, params = rig
+    cache = init_cache(cfg, 1, 32)
+    tokens = (jnp.zeros((1, 4), jnp.int32) if program == "_prefill"
+              else jnp.zeros((1,), jnp.int32))
+    text = getattr(engine, program).lower(cfg, params, tokens, cache).as_text()
+    name = text.split("module @", 1)[1].split()[0]
+    assert name == {"_prefill": "jit_prefill", "_decode": "jit_decode_step"}[program]
+
+    # the reader, over a window holding one execution of that program as
+    # the chip's trace names it
+    root = Path(__file__).resolve().parents[1]
+    raw = {"devices": {"0": {"ops": [], "modules": [[f"{name}(4711)", 100, 50]]}},
+           "host": [["bench.window.begin", 0, 0], ["bench.window.end", 1000, 0]]}
+    conf = json.loads((root / "bench/configs/phi3-mini-3.8b/config.json").read_text())
+    counts = {"decoded_tokens": 1, "decode_flops": 1.0, "decode_kv_bytes": 1.0}
+    r = Readings(trace=Trace(raw), counts=counts, config=conf, traffic={},
+                 peak=peaks.peak("TPU v5 lite"), chips=1)
+    assert load_module(root / "bench" / "layers" / f"{reader}.py").read(r) is not None
