@@ -298,6 +298,30 @@ def attention(q, k, v, *, causal: bool = True, q_offset=0,
     return out.astype(q.dtype)
 
 
+def _attend_rows(q, k_new, v_new, k_cache, v_cache, at):
+    """One query per row against that row's cached keys before ``at[b]``
+    and its own new key (position ``at[b]``, not yet in the cache): the
+    decode fast path of :func:`attention` with the new key kept apart.
+    q, k_new, v_new: (B, 1, ·, D); k_cache, v_cache: (B, S, K, D)."""
+    B, _, H, D = q.shape
+    S, K = k_cache.shape[1], k_cache.shape[2]
+    scale = 1.0 / math.sqrt(D)
+    qh = q.reshape(B, K, H // K, D).astype(jnp.float32)
+    s = jnp.einsum("bkgd,bskd->bkgs", qh,
+                   k_cache.astype(jnp.float32)) * scale
+    kpos = jnp.arange(S)
+    mask = kpos < at[:, None]
+    s = jnp.where(mask[:, None, None], s, -jnp.inf)
+    s_new = jnp.einsum("bkgd,bkd->bkg", qh,
+                       k_new[:, 0].astype(jnp.float32)) * scale
+    m = jnp.maximum(s.max(axis=-1), s_new)          # the new key is valid
+    p, p_new = jnp.exp(s - m[..., None]), jnp.exp(s_new - m)
+    out = (jnp.einsum("bkgs,bskd->bkgd", p, v_cache.astype(jnp.float32))
+           + p_new[..., None] * v_new[:, 0, :, None].astype(jnp.float32))
+    out = out / (p.sum(axis=-1) + p_new)[..., None]
+    return out.reshape(B, 1, H, v_cache.shape[-1]).astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # GQA attention layer (mistral / deepseek-coder / minicpm / phi3 / musicgen /
 # qwen2-vl / recurrentgemma-local)
@@ -320,6 +344,12 @@ def attn_forward(cfg, p: Params, x, positions, cache=None, *,
     """x: (B, S, d).  cache: dict(k, v, length) for decode, or None.
 
     Returns (out, new_cache).  KV cache layout: (B, S_max, K, hd).
+    ``length`` is a scalar (every row at one position) or, for the plain
+    full cache in a one-token decode, (B,): each row then attends up to
+    its own length (the ragged batched decode step), and the returned
+    cache holds the rows' new K/V, (B, 1, K, hd), which
+    ``transformer._run_group_rows`` writes into the group's stacks after
+    its layer scan, in place.
     """
     B, S, d = x.shape
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
@@ -346,7 +376,7 @@ def attn_forward(cfg, p: Params, x, positions, cache=None, *,
     k = apply_rope(k, positions, cfg.rope_theta, cfg.m_rope_sections)
     window = cfg.rec.local_window if local else None
     if cache is not None:
-        length = cache["length"]                       # scalar int32
+        length = cache["length"]              # scalar, or (B,) per row
         W = cache["k"].shape[1]
         if local and W <= window:
             # ---- ring-buffer cache: holds only the last W tokens ----
@@ -377,7 +407,16 @@ def attn_forward(cfg, p: Params, x, positions, cache=None, *,
             from ..distributed.context import decode_shard_info
             info = decode_shard_info(B, cache["k"].shape[1]) \
                 if S == 1 and not local else None
-            if info is not None:
+            if length.ndim:
+                # one sequence per row: each row attends to its cached
+                # keys before its own length (an idle row's is held
+                # inside the cache) and to its new key, which the caller
+                # writes into the cache (transformer._run_group_rows)
+                out = _attend_rows(q, k, v, cache["k"].astype(cdt),
+                                   cache["v"].astype(cdt),
+                                   jnp.minimum(length, W - 1))
+                new_cache = {"k": k, "v": v, "length": length + 1}
+            elif info is not None:
                 # §Perf M1: shard_map flash-decode — local one-row cache
                 # update + partial-softmax combine (KB-scale collectives)
                 # instead of pjit DUS on a sharded dim (which replicates

@@ -179,6 +179,46 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
     return caches
 
 
+def takes_rows(cfg: ModelConfig) -> bool:
+    """Whether every layer can decode its batch rows at positions of
+    their own (:func:`rows_cache`): plain full attention with a dense
+    FFN.  Ring windows, latent caches and recurrent state keep one
+    position per cache; routed experts share a capacity between the
+    rows, so one row's answer would depend on the others."""
+    return all(map(_group_takes_rows, cfg.groups))
+
+
+def _group_takes_rows(g: LayerGroup) -> bool:
+    return all(m == "attn" and g.ffn_of(i) != "moe"
+               for i, m in enumerate(g.pattern))
+
+
+def rows_cache(cfg: ModelConfig, batch: int, max_len: int,
+               dtype=jnp.bfloat16) -> list:
+    """:func:`init_cache` with one length per row (stacked: (count,
+    batch)), for a decode step in which each row is a sequence at its
+    own position (``layers.attn_forward``).  Needs :func:`takes_rows`."""
+    if not takes_rows(cfg):
+        raise ValueError(f"{cfg.arch_id}: some layer cannot decode its "
+                         "rows at positions of their own")
+    return [{name: {**sub, "length": jnp.zeros(
+                (*sub["length"].shape, batch), jnp.int32)}
+             for name, sub in gc.items()}
+            for gc in init_cache(cfg, batch, max_len, dtype)]
+
+
+def insert_row(caches: list, row: list, slot) -> list:
+    """``row`` (a batch-1 cache, as :func:`prefill` leaves it) written
+    over row ``slot`` of a :func:`rows_cache`, whose length there becomes
+    the row cache's length."""
+    def put(big, small):
+        if big.ndim == small.ndim:        # K/V: (count, B, ...) ← (count, 1, ...)
+            return jax.lax.dynamic_update_slice_in_dim(
+                big, small.astype(big.dtype), slot, axis=1)
+        return big.at[:, slot].set(small)  # lengths: (count, B) ← (count,)
+    return jax.tree.map(put, caches, row)
+
+
 def cache_specs(cfg: ModelConfig, batch: int, max_len: int,
                 dtype=jnp.bfloat16) -> list:
     return jax.eval_shape(lambda: init_cache(cfg, batch, max_len, dtype))
@@ -220,6 +260,9 @@ def _run_group(cfg, g: LayerGroup, gp: Params, x, positions, gcache,
         body_fn = jax.checkpoint(
             body_fn, policy=policy, static_argnums=())
 
+    if gcache is not None and any(c["length"].ndim == 2
+                                  for c in gcache.values() if "length" in c):
+        return _run_group_rows(g, body_fn, gp, x, gcache)
     if gcache is None:
         def scan_body(carry, lp):
             x, aux = carry
@@ -236,6 +279,45 @@ def _run_group(cfg, g: LayerGroup, gp: Params, x, positions, gcache,
         (x, aux), new_cache = jax.lax.scan(
             scan_body, (x, jnp.zeros((), jnp.float32)), (gp, gcache))
         return x, new_cache, aux
+
+
+def _run_group_rows(g: LayerGroup, body_fn, gp: Params, x, gcache):
+    """Scan one layer group over a :func:`rows_cache`.
+
+    Each layer reads its K/V as scan inputs and returns only its rows'
+    new K/V, which are written into the stacks after the scan, each at
+    its row's length.  The stacks are never scan outputs: as outputs
+    every layer's whole slice would be rewritten, and copied whole
+    again out of the loop, on every call."""
+    if x.shape[1] != 1 or not _group_takes_rows(g):
+        raise ValueError("per-row cache lengths take only a one-token "
+                         "decode step over full attention layers with a "
+                         "dense FFN")
+
+    def scan_body(carry, xs):
+        x, aux = carry
+        x, nc, aux_i = body_fn(x, *xs)
+        return (x, aux + aux_i), {n: (c["k"], c["v"]) for n, c in nc.items()}
+
+    (x, aux), new = jax.lax.scan(
+        scan_body, (x, jnp.zeros((), jnp.float32)), (gp, gcache))
+    out = {}
+    for n, c in gcache.items():
+        at = jnp.minimum(c["length"][0], c["k"].shape[2] - 1)
+        out[n] = {"k": _write_rows(c["k"], new[n][0], at),
+                  "v": _write_rows(c["v"], new[n][1], at),
+                  "length": c["length"] + 1}
+    return x, out, aux
+
+
+def _write_rows(stack, new, at):
+    """``new`` (count, B, 1, ...) written into ``stack`` (count, B, S,
+    ...), row ``b`` at position ``at[b]``."""
+    for b in range(stack.shape[1]):
+        stack = jax.lax.dynamic_update_slice(
+            stack, new[:, b:b + 1].astype(stack.dtype),
+            (0, b, at[b]) + (0,) * (stack.ndim - 3))
+    return stack
 
 
 def forward(cfg: ModelConfig, params: Params, tokens=None, *,
@@ -270,6 +352,8 @@ def forward(cfg: ModelConfig, params: Params, tokens=None, *,
         offset = 0
         if caches is not None:
             offset = _cache_length(caches)
+            if offset.ndim:                   # per-row lengths: (B, 1)
+                offset = offset[:, None]
         pos1d = offset + jnp.arange(S)[None, :]
         pos1d = jnp.broadcast_to(pos1d, (B, S))
         if cfg.m_rope_sections:
@@ -296,14 +380,15 @@ def forward(cfg: ModelConfig, params: Params, tokens=None, *,
 
 
 def _cache_length(caches) -> jax.Array:
-    """Extract the scalar cache length (any attn/mla sub-cache carries it;
-    pure-recurrent stacks track an explicit counter)."""
+    """Extract the cache length: a scalar, or (B,) for a :func:`rows_cache`
+    (any attn/mla sub-cache carries it; pure-recurrent stacks track an
+    explicit counter)."""
     for gc in caches:
         for sub in gc.values():
             if isinstance(sub, dict) and "length" in sub:
                 ln = sub["length"]
-                # stacked over count: all equal — take element 0
-                return ln.reshape(-1)[0]
+                # stacked over count: all equal — take layer 0
+                return ln[0] if ln.ndim == 2 else ln.reshape(-1)[0]
     return jnp.zeros((), jnp.int32)
 
 

@@ -37,15 +37,33 @@ latency — and :meth:`stats` is a back-compat view over it.  Pass
 preemptions, KV migrations, and bin join/retire/fail on the same
 timeline as the executor's spans.
 
+**Ragged batched decode**: where every layer can decode its rows at
+positions of their own (``transformer.takes_rows``: full attention,
+dense FFN) the slots are the rows of one stacked KV cache, each row at
+its own length, and a tick decodes every row in one call of
+``max_slots`` rows (an empty slot's row decodes a dummy token whose
+result is dropped, so one program serves any occupancy).  The cache is
+donated, so each row's new K/V is written in place; the greedy token of
+every row is taken on the device and read back in one transfer.  A
+prefill still runs batch-1, into a cache its program makes, and the
+same program, given the stacked cache donated, writes that cache over
+the slot's whole row.  Ring
+windows, latent caches, recurrent state and routed experts keep one
+batch-1 cache per slot and one call per seated slot.
+
 **Spans**: every tick opens ``repro.obs.span``s, always on, that land
 in a ``jax.profiler`` trace (and in the ``obs=`` ring when given):
 ``engine.tick`` (stats ``active``, ``queued``, set at its end) holds
 ``engine.schedule`` (``event``, ``request``, ``nodes``) around each call
-into placement, ``engine.prefill`` (``request``, ``tokens``) and
-``engine.decode`` (``request``, ``slot``) around each dispatch of the
-model, and ``engine.read`` (``request``) around each host read of a
-token.  The names are a contract with the benchmark's readers
-(docs/observability.md).
+into placement, ``engine.prefill`` (``request``, ``tokens``) around each
+prefill (its row's write included), one ``engine.decode`` (``rows``: seated rows
+in the call) around the tick's decode call and one ``engine.read``
+(``rows``) around its host read; the batch-1 path opens one
+``engine.decode`` (``request``, ``slot``, ``rows`` = 1) per seated slot,
+and every prefill's token and batch-1 decode's token has its own
+``engine.read`` (``request``).  ``engine.metrics``' ``decode_rows``
+histogram counts the rows of each decode call.  The names are a
+contract with the benchmark's readers (docs/observability.md).
 
 KV capacity is governed per bin by the :class:`PagedKVArena` buddy pool —
 a request is admitted only when its bin's arena can host its page run
@@ -106,10 +124,32 @@ LIFECYCLE = (QUEUED, PREFILL, DECODING, DONE, PREEMPTED)
 _PREFILL_COST_PER_TOKEN = 2.0
 _DECODE_COST_PER_TOKEN = 6.0
 
+
+def prefill(cfg: ModelConfig, params, tokens, max_seq: int, rows=None,
+            slot=None):
+    """The prompt's last logits and its batch-1 cache of ``max_seq``
+    positions, made inside the program, so no empty cache is allocated
+    and read beside the one it returns.  Given a stacked cache ``rows``
+    (donated), that cache is written over the whole of row ``slot`` in
+    the same program, and the stack is returned in its place."""
+    logits, cache = transformer.prefill(
+        cfg, params, tokens, transformer.init_cache(cfg, 1, max_seq))
+    if rows is None:
+        return logits, cache
+    return logits, transformer.insert_row(rows, cache, slot)
+
+
 # jitted once per process with the config static, so every engine over
-# one config shares the compiled steps
-_prefill = jax.jit(transformer.prefill, static_argnums=0)
-_decode = jax.jit(transformer.decode_step, static_argnums=0)
+# one config shares the compiled steps; decode and the stacked prefill
+# donate the cache they are given, so it is written in place
+_prefill = jax.jit(prefill, static_argnums=(0, 3), donate_argnums=4)
+_decode = jax.jit(transformer.decode_step, static_argnums=0, donate_argnums=3)
+
+
+@jax.jit
+def greedy(logits):
+    """The best token of every row, on the device."""
+    return jnp.argmax(logits, axis=-1)
 
 
 @dataclass(frozen=True, eq=False)
@@ -150,7 +190,8 @@ class ServingEngine:
     """Slot-based continuous batching over one or more model replicas.
 
     ``max_slots`` concurrent requests share a stacked KV cache of
-    ``max_seq`` tokens per slot; each bin's paged arena does admission
+    ``max_seq`` tokens per slot (module docstring: ragged batched
+    decode); each bin's paged arena does admission
     control and utilization accounting, and the ``scheduler`` policy
     places request groups onto bins through the event-driven
     ``update()`` loop.  Greedy sampling (argmax) — sampling strategies
@@ -205,9 +246,16 @@ class ServingEngine:
         self._lock = threading.Lock()
         self.completed: list[Request] = []
 
-        # per-slot caches (each slot = batch-1 cache ⇒ independent prefill)
-        self._caches = [transformer.init_cache(cfg, 1, max_seq)
-                        for _ in range(max_slots)]
+        # one stacked cache, a row per slot at its own length, decoded in
+        # one call per tick; where a layer keeps one position per cache
+        # (ring window, latent cache, recurrent state) or couples its rows
+        # (routed experts), one batch-1 cache per slot, decoded one by one
+        if transformer.takes_rows(cfg):
+            self._rows = transformer.rows_cache(cfg, max_slots, max_seq)
+        else:
+            self._rows = None
+            self._caches = [transformer.init_cache(cfg, 1, max_seq)
+                            for _ in range(max_slots)]
         self._obs = obs
         #: public registry — counters/histograms the engine publishes
         #: into; :meth:`stats` is a back-compat view over it
@@ -218,6 +266,7 @@ class ServingEngine:
         self._kv_move_seconds = self.metrics.counter("kv_move_seconds")
         self._ttft = self.metrics.histogram("ttft_s")
         self._itl = self.metrics.histogram("itl_s")
+        self._call_rows = self.metrics.histogram("decode_rows")
         self._last_token_s: dict[int, float] = {}
 
     def _new_arena(self, n_pages: int) -> PagedKVArena:
@@ -506,14 +555,7 @@ class ServingEngine:
                     self._req_groups[req.id] = groups
                     del self._placed[req.id]
                     req._advance(state=PREFILL)
-                    # prefill this slot
-                    with span("engine.prefill", self._obs, request=req.id,
-                              tokens=len(req.prompt)):
-                        tokens = jnp.asarray(req.prompt[None, :])
-                        self._caches[i] = transformer.init_cache(
-                            self.cfg, 1, self.max_seq)
-                        logits, self._caches[i] = _prefill(
-                            self.cfg, self.params, tokens, self._caches[i])
+                    logits = self._prefill_slot(i, req)
                     req.generated.append(self._read(req, logits))
                     now = self._clock()
                     if req.first_token_s is None:
@@ -527,29 +569,81 @@ class ServingEngine:
                     if dbin != home:
                         self._migrate_kv(req, dbin)
 
-        # 2. decode step for all active slots
-        active = [(i, r) for i, r in enumerate(self._slots) if r is not None]
-        for i, req in active:
+        # 2. decode step for all active slots (their prefill may already
+        # have given the last token)
+        for i, req in enumerate(list(self._slots)):
+            if req is not None and len(req.generated) >= req.max_new_tokens:
+                self._retire(i)
+        seated = [(i, r) for i, r in enumerate(self._slots) if r is not None]
+        if self._rows is not None:
+            self._decode_stacked(seated)
+        else:
+            self._decode_slots(seated)
+        return self._has_work()
+
+    def _prefill_slot(self, i: int, req: Request):
+        """Prefill ``req`` into a fresh batch-1 cache and seat that cache
+        in slot ``i``: as the slot's own cache, or, in the same program,
+        written over the whole of row ``i`` of the stacked cache.
+        Returns the prompt's last logits."""
+        with span("engine.prefill", self._obs, request=req.id,
+                  tokens=len(req.prompt)):
+            tokens = jnp.asarray(req.prompt[None, :])
+            if self._rows is None:
+                logits, self._caches[i] = _prefill(
+                    self.cfg, self.params, tokens, self.max_seq)
+            else:
+                logits, self._rows = _prefill(
+                    self.cfg, self.params, tokens, self.max_seq, self._rows,
+                    i)
+        return logits
+
+    def _decode_stacked(self, seated: list[tuple[int, Request]]) -> None:
+        """Every row of the stacked cache in one call; an empty slot's
+        row decodes a dummy token whose result is dropped, so the call
+        has one shape however many slots are seated."""
+        if not seated:
+            return
+        tokens = np.zeros(self.max_slots, np.int32)
+        for i, req in seated:
+            tokens[i] = req.generated[-1]
+        with span("engine.decode", self._obs, rows=len(seated)):
+            logits, self._rows = _decode(self.cfg, self.params,
+                                         jnp.asarray(tokens), self._rows)
+            best = greedy(logits)
+        self._call_rows.observe(len(seated))
+        with span("engine.read", self._obs, rows=len(seated)):
+            best = np.asarray(best)
+        for i, req in seated:
+            if self._slots[i] is req:             # else preempted by a grow
+                self._emit(i, req, int(best[i]))
+
+    def _decode_slots(self, seated: list[tuple[int, Request]]) -> None:
+        """One batch-1 call and one read per seated slot."""
+        for i, req in seated:
             if self._slots[i] is not req:
                 continue                          # preempted mid-tick
-            if len(req.generated) >= req.max_new_tokens:
-                self._retire(i)
-                continue
-            with span("engine.decode", self._obs, request=req.id, slot=i):
+            with span("engine.decode", self._obs, request=req.id, slot=i,
+                      rows=1):
                 tok = jnp.asarray([req.generated[-1]], jnp.int32)
                 logits, self._caches[i] = _decode(
                     self.cfg, self.params, tok, self._caches[i])
-            req.generated.append(self._read(req, logits))
-            now = self._clock()
-            last = self._last_token_s.get(req.id)
-            if last is not None:
-                self._itl.observe(now - last)
-            self._last_token_s[req.id] = now
-            if not self._grow(req):
-                continue                          # req went back to queue
-            if len(req.generated) >= req.max_new_tokens:
-                self._retire(i)
-        return self._has_work()
+            self._call_rows.observe(1)
+            self._emit(i, req, self._read(req, logits))
+
+    def _emit(self, i: int, req: Request, token: int) -> None:
+        """Hand ``req`` its decoded token: ITL sample, page grow (which
+        may preempt), retirement at its last token."""
+        req.generated.append(token)
+        now = self._clock()
+        last = self._last_token_s.get(req.id)
+        if last is not None:
+            self._itl.observe(now - last)
+        self._last_token_s[req.id] = now
+        if not self._grow(req):
+            return                                # req went back to queue
+        if len(req.generated) >= req.max_new_tokens:
+            self._retire(i)
 
     def _read(self, req: Request, logits) -> int:
         """The greedy token, read back to the host (waits for the

@@ -13,9 +13,11 @@ from repro.models import (decode_step, forward, init_cache, init_params,
                           loss_fn, prefill)
 from repro.models.frontends import make_patch_embeds
 from repro.models.layers import dense_init
-from repro.models.transformer import init_layer
+from repro.models.transformer import (init_layer, insert_row, rows_cache,
+                                      takes_rows)
 
 ARCHS = list_archs()
+ROWS_ARCHS = [a for a in ARCHS if takes_rows(get_config(a))]
 
 
 @pytest.fixture(scope="module")
@@ -150,3 +152,69 @@ def test_grad_flows_through_every_param():
         # batch; everything else must be live
         dead = [d for d in dead if "expert" not in d and "router" not in d]
         assert not dead, f"{arch}: dead grads at {dead[:5]}"
+
+
+# ----------------------------------------------------------------------
+# ragged decode: one step over a cache whose rows sit at their own lengths
+# ----------------------------------------------------------------------
+def _set_length(caches, row, n):
+    return [{k: {**sub, "length": sub["length"].at[:, row].set(n)}
+             for k, sub in gc.items()} for gc in caches]
+
+
+@pytest.mark.parametrize("arch", ROWS_ARCHS)
+def test_rows_decode_matches_each_rows_own_decode(rigs, arch):
+    """Each row of one decode step over a :func:`rows_cache` gives the
+    logits and K/V of that row's own batch-1 decode: rows at different
+    lengths, one at ``max_seq - 1``, and an idle row held past the end
+    (finite, and writing only inside its own row)."""
+    cfg, params = rigs[arch]
+    T, idle = 16, 2
+    lens = {0: 5, 1: 9, 3: T - 1}
+    caches = rows_cache(cfg, 4, T)
+    rng = np.random.default_rng(0)
+    toks, own = np.zeros(4, np.int32), {}
+    for b, n in lens.items():
+        prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, n)), jnp.int32)
+        logits, own[b] = prefill(cfg, params, prompt, init_cache(cfg, 1, T))
+        caches = insert_row(caches, own[b], b)
+        toks[b] = int(jnp.argmax(logits[0]))
+    caches = _set_length(caches, idle, T + 3)
+    before = caches[0]["sub0"]["k"][:, idle]
+
+    logits, new = decode_step(cfg, params, jnp.asarray(toks), caches)
+    assert logits.shape == (4, cfg.vocab_size)
+    assert np.isfinite(np.asarray(logits)).all()
+    sub = new[0]["sub0"]
+    # a batch of rows may round differently from one row in bf16 compute
+    tol = 2e-5 if cfg.compute_dtype == "float32" else 3e-2
+    for b, c in own.items():
+        want, c1 = decode_step(cfg, params, jnp.asarray(toks[b:b + 1]), c)
+        np.testing.assert_allclose(logits[b], want[0], rtol=tol, atol=tol)
+        for name in ("k", "v"):
+            np.testing.assert_allclose(
+                np.asarray(sub[name][:, b], np.float32),
+                np.asarray(c1[0]["sub0"][name][:, 0], np.float32),
+                rtol=tol, atol=tol)
+    np.testing.assert_array_equal(
+        sub["length"][0], [lens[0] + 1, lens[1] + 1, T + 4, lens[3] + 1])
+    np.testing.assert_array_equal(sub["k"][:, idle, :T - 1],
+                                  before[:, :T - 1])
+
+
+@pytest.mark.parametrize("arch", ["recurrentgemma-2b", "deepseek-v2-236b"])
+def test_rows_cache_refused_where_a_layer_keeps_one_position(rigs, arch):
+    """Ring windows and latent caches keep their scalar length: the
+    config cannot build a per-row cache, and a per-row length handed to
+    the layer raises rather than being broadcast."""
+    cfg, params = rigs[arch]
+    assert not takes_rows(cfg)
+    with pytest.raises(ValueError):
+        rows_cache(cfg, 2, 16)
+    caches = init_cache(cfg, 2, 16)
+    ragged = [{k: ({**sub, "length": jnp.zeros((*sub["length"].shape, 2),
+                                               jnp.int32)}
+                   if "length" in sub else sub)
+               for k, sub in gc.items()} for gc in caches]
+    with pytest.raises(ValueError, match="length"):
+        decode_step(cfg, params, jnp.zeros((2,), jnp.int32), ragged)

@@ -102,6 +102,91 @@ def test_engine_matches_raw_decode(rig):
     assert got == want
 
 
+def _direct_tokens(cfg, params, prompt, n, max_seq):
+    """``n`` greedy tokens of one request by a batch-1 prefill and a
+    ``decode_step`` loop, without the engine."""
+    import jax.numpy as jnp
+    from repro.models import decode_step, init_cache, prefill
+
+    logits, caches = jax.jit(prefill, static_argnums=0)(
+        cfg, params, jnp.asarray(prompt[None]), init_cache(cfg, 1, max_seq))
+    out = [int(jnp.argmax(logits[0]))]
+    while len(out) < n:
+        logits, caches = jax.jit(decode_step, static_argnums=0)(
+            cfg, params, jnp.asarray([out[-1]], jnp.int32), caches)
+        out.append(int(jnp.argmax(logits[0])))
+    return out
+
+
+def test_engine_rows_match_direct_decode_per_request(rig, monkeypatch):
+    """The stacked ragged decode gives every request the tokens of its
+    own prefill and batch-1 decode loop: five requests of different
+    prompt and answer lengths, seated at different ticks on two slots
+    (so slots retire and re-seat), one of them preempted by a grow that
+    found the arena full, re-queued and recomputed."""
+    from repro.core.memory import OutOfMemory
+    from repro.serving.kv_cache import PagedKVArena
+
+    cfg, params = rig
+    cfg = dataclasses.replace(cfg, compute_dtype="float32")
+    eng = ServingEngine(cfg, params, max_slots=2, max_seq=32)
+    assert eng._rows is not None
+    rng = np.random.default_rng(5)
+    asks = [(5, 6), (9, 3), (3, 7), (7, 4), (4, 5)]      # (prompt, answer)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n, _ in asks]
+
+    # the grower's page-run grow meets a full arena once, when request 2
+    # holds its third token: the engine preempts the other seated one
+    real = PagedKVArena.extend
+    planted = {"left": 1}
+
+    def extend(arena, rid, new_tokens=1):
+        req = next(r for r in eng._slots if r is not None and r.id == rid)
+        if rid == 2 and planted["left"] and len(req.generated) == 3:
+            planted["left"] = 0
+            raise OutOfMemory("planted: arena full")
+        return real(arena, rid, new_tokens)
+
+    monkeypatch.setattr(PagedKVArena, "extend", extend)
+    ids = [eng.submit(prompts[i], max_new_tokens=asks[i][1]) for i in (0, 1)]
+    eng.step()
+    ids.append(eng.submit(prompts[2], max_new_tokens=asks[2][1]))
+    eng.step()
+    ids += [eng.submit(prompts[i], max_new_tokens=asks[i][1]) for i in (3, 4)]
+    done = {r.id: r.generated for r in eng.run()}
+
+    assert planted["left"] == 0 and eng.preemptions == 1
+    assert sorted(done) == ids == list(range(5))
+    for i, (prompt, (_, n)) in enumerate(zip(prompts, asks)):
+        assert done[i] == _direct_tokens(cfg, params, prompt, n, 32), i
+    # one call per tick with seated rows; the victim's row was decoded
+    # in the call whose grow preempted it, and its tokens were dropped
+    rows = eng.metrics.histogram("decode_rows").samples
+    assert set(rows) == {1, 2} and len(rows) <= eng.ticks
+    assert sum(rows) > sum(n - 1 for _, n in asks)
+
+
+@pytest.mark.parametrize("arch", ["recurrentgemma-2b", "deepseek-v2-236b"])
+def test_engine_serves_one_position_layers_batch1(arch):
+    """A config with a ring window (``attn_local``) or a latent cache
+    (``mla``) still serves, through one batch-1 cache and call per slot,
+    with each request's own tokens."""
+    cfg = reduced(get_config(arch))
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, max_slots=2, max_seq=32)
+    assert eng._rows is None
+    asks = [(5, 3), (8, 2), (4, 4)]
+    prompts = [np.arange(n, dtype=np.int32) * 7 % cfg.vocab_size
+               for n, _ in asks]
+    for p, (_, k) in zip(prompts, asks):
+        eng.submit(p, max_new_tokens=k)
+    done = {r.id: r.generated for r in eng.run()}
+    for i, (p, (_, k)) in enumerate(zip(prompts, asks)):
+        assert done[i] == _direct_tokens(cfg, params, p, k, 32)
+    assert set(eng.metrics.histogram("decode_rows").samples) == {1}
+
+
 def test_oversize_reject_retries_slot_in_same_tick(rig):
     """Rejecting an oversize request must not waste the slot for the
     whole tick: the next queued request is seated immediately."""
@@ -290,8 +375,9 @@ def test_engine_add_and_retire_bin(rig):
 # ----------------------------------------------------------------------
 def test_engine_spans_nest_in_ticks(rig, tmp_path):
     """One ``engine.tick`` per step; every other engine span lies inside
-    a tick and names its request; placement is entered once per
-    admission and once per retirement."""
+    a tick and names its request, or for the tick's one decode call and
+    its one read, its rows; placement is entered once per admission and
+    once per retirement."""
     cfg, params = rig
     eng = ServingEngine(cfg, params, max_slots=2, max_seq=64)
     asks = ((5, 3), (6, 2), (5, 4))               # (prompt, new tokens)
@@ -317,8 +403,10 @@ def test_engine_spans_nest_in_ticks(rig, tmp_path):
     inner = [s for s in got if s.name.startswith("engine.")
              and s.name != "engine.tick"]
     assert all(any(t.holds(s) for t in ticks) for s in inner)
-    assert all(s.stats["request"] in ids for s in inner
-               if s.name != "engine.schedule")
+    assert all(s.stats["request"] in ids if "request" in s.stats
+               else s.name in ("engine.decode", "engine.read")
+               and s.stats["rows"] >= 1
+               for s in inner if s.name != "engine.schedule")
 
     assert eng.preemptions == 0
     sched = named("engine.schedule")
@@ -330,13 +418,19 @@ def test_engine_spans_nest_in_ticks(rig, tmp_path):
     prefills = named("engine.prefill")
     assert sorted((s.stats["request"], s.stats["tokens"]) for s in prefills) \
         == sorted(zip(ids, (n for n, _ in asks)))
+    # one decode call and one read per tick with seated rows: both
+    # requests, then the second and the third, then the third alone
     decodes, reads = named("engine.decode"), named("engine.read")
-    assert len(decodes) == sum(k - 1 for _, k in asks)
+    assert [d.stats["rows"] for d in decodes] == [2, 2, 1, 1]
+    assert sum(d.stats["rows"] for d in decodes) == sum(k - 1 for _, k in asks)
+    assert eng.metrics.histogram("decode_rows").samples == [2, 2, 1, 1]
+    assert [sum(t.holds(d) for d in decodes) for t in ticks] == [1] * steps
     assert len(reads) == len(prefills) + len(decodes)
-    assert all(s.stats["slot"] in (0, 1) for s in decodes)
-    for d in decodes:                              # its token's read follows
+    assert sorted(r.stats["request"] for r in reads if "request" in r.stats) \
+        == sorted(ids)                             # each prefill's token
+    for d in decodes:                              # its rows' read follows
         r = next(r for r in reads if r.start_ns >= d.end_ns)
-        assert r.stats["request"] == d.stats["request"]
+        assert r.stats == {"rows": d.stats["rows"]}
 
 
 def test_engine_spans_reach_flight_recorder(rig):
@@ -373,16 +467,21 @@ def test_engine_program_names_match_bench_readers(rig, program, reader):
     from bench.common import peaks
     from bench.common.harness import Readings, load_module
     from bench.common.trace import Trace
-    from repro.models import init_cache
     from repro.serving import engine
 
     cfg, params = rig
-    cache = init_cache(cfg, 1, 32)
-    tokens = (jnp.zeros((1, 4), jnp.int32) if program == "_prefill"
-              else jnp.zeros((1,), jnp.int32))
-    text = getattr(engine, program).lower(cfg, params, tokens, cache).as_text()
+    # the engine's stacked cache, donated: the prefill writes its fresh
+    # cache of 32 positions over row 1, the decode step every row
+    eng = engine.ServingEngine(cfg, params, max_slots=2, max_seq=32)
+    if program == "_prefill":
+        args = (jnp.zeros((1, 4), jnp.int32), 32, eng._rows, 1)
+    else:
+        args = (jnp.zeros((2,), jnp.int32), eng._rows)
+    text = getattr(engine, program).lower(cfg, params, *args).as_text()
     name = text.split("module @", 1)[1].split()[0]
     assert name == {"_prefill": "jit_prefill", "_decode": "jit_decode_step"}[program]
+    # each leaf of the stacked cache aliases an output
+    assert text.count("tf.aliasing_output") == len(jax.tree.leaves(eng._rows))
 
     # the reader, over a window holding one execution of that program as
     # the chip's trace names it

@@ -1,5 +1,6 @@
 """Compiles for a described TPU v5e chip: the four Pallas kernels at one
-real-width shape each, and the phi3-mini bf16 decode step.
+real-width shape each, the phi3-mini bf16 decode step, and the serving
+engine's donated ragged decode over its stacked cache.
 
 Nothing runs: the TPU compiler refuses what a chip would refuse (block
 shapes, unlowered primitives, device memory), so these guard the chip
@@ -7,6 +8,7 @@ path from a CPU host.  The topology is described inside a fixture, never
 at import, so only the worker that runs this file loads the TPU library.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -90,3 +92,37 @@ def test_phi3_bf16_decode_step_compiles_for_v5e(one_chip):
     assert all(x.dtype == jnp.bfloat16 for x in jax.tree.leaves(params))
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < V5E_HBM_BYTES)
+
+
+@pytest.mark.parametrize("program", ["_decode", "_prefill"])
+def test_engine_rows_programs_alias_their_cache_on_v5e(one_chip, program):
+    """The serving engine's decode step and prefill, each donated the
+    phi3-mini stacked cache of 8 rows x 1280 tokens in bf16 (the prefill
+    a 1024-token prompt, written over one row): the cache is written in
+    place (its 4.03 GB aliased, no whole-cache copy in the program), and
+    the weights, the cache and the temporaries fit the chip."""
+    from repro.serving import engine
+
+    cfg = dataclasses.replace(get_config("phi3-mini-3.8b"),
+                              param_dtype="bfloat16")
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: _spec(one_chip, s.shape, s.dtype), tree)
+    params = on_chip(transformer.param_specs(cfg))
+    caches = on_chip(jax.eval_shape(
+        lambda: transformer.rows_cache(cfg, 8, 1280)))
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(caches))
+    assert cache_bytes > 4.02e9
+    if program == "_decode":
+        args = (_spec(one_chip, (8,), jnp.int32), caches)
+    else:
+        args = (_spec(one_chip, (1, 1024), jnp.int32), 1280, caches,
+                _spec(one_chip, (), jnp.int32))
+    compiled = getattr(engine, program).lower(cfg, params, *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes - 2**20   # lengths aside
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            < V5E_HBM_BYTES)
+    whole = r"bf16\[32,8,1280,32,96\]\{[^}]*\} copy\("
+    assert not re.search(whole, compiled.as_text())
