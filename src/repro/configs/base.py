@@ -89,6 +89,14 @@ class ModelConfig:
     frontend: Literal["none", "audio_stub", "vision_stub"] = "none"
     n_visual_tokens: int = 0      # stub frontend token count (vlm)
     norm_eps: float = 1e-5
+    # muP multipliers of the residual path (MiniCPM): the token embeddings
+    # times ``embed_scale``, each residual branch (mixer, FFN) times
+    # ``residual_scale`` before it is added, and the final normed hidden
+    # state times ``logit_scale`` before the output head.  Constants of
+    # the model; at 1 no op is traced.
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
     # numerics
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"

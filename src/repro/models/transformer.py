@@ -68,11 +68,18 @@ def _mixer_forward(cfg, mixer: str, p, x, positions, cache):
     raise ValueError(mixer)
 
 
+def _scaled(x, s: float):
+    """``x`` times a constant of the model; nothing is traced at 1."""
+    return x if s == 1 else x * s
+
+
 def _block_forward(cfg, mixer: str, ffn: str, p: Params, x, positions, cache):
-    """Pre-norm residual block: x + mixer(norm(x)); x + ffn(norm(x))."""
+    """Pre-norm residual block: x + s·mixer(norm(x)); x + s·ffn(norm(x)),
+    with s = ``cfg.residual_scale``."""
     h, new_cache = _mixer_forward(
         cfg, mixer, p["mixer"], L.rms_norm(x, p["norm1"], cfg.norm_eps),
         positions, cache)
+    h = _scaled(h, cfg.residual_scale)
     # branch outputs re-enter the seq-sharded residual layout HERE so the
     # post-projection partial sums lower as reduce-scatters of the
     # (B/dp, S/tp, d) shard instead of full-seq all-reduces (§Perf D4)
@@ -83,10 +90,12 @@ def _block_forward(cfg, mixer: str, ffn: str, p: Params, x, positions, cache):
     if ffn == "dense":
         h = L.ffn_forward(cfg, p["ffn"], L.rms_norm(x, p["norm2"],
                                                     cfg.norm_eps))
+        h = _scaled(h, cfg.residual_scale)
         x = x + constrain(h, "dtp_features" if dec else "residual")
     elif ffn == "moe":
         h, aux = M.moe_forward(cfg, p["ffn"], L.rms_norm(x, p["norm2"],
                                                          cfg.norm_eps))
+        h = _scaled(h, cfg.residual_scale)
         x = x + constrain(h, "dtp_features" if dec else "residual")
     return x, new_cache, aux
 
@@ -339,7 +348,9 @@ def forward(cfg: ModelConfig, params: Params, tokens=None, *,
     if extra_embeds is not None:
         parts.append(extra_embeds.astype(cdt))
     if tokens is not None:
-        parts.append(jnp.take(params["embed"], tokens, axis=0).astype(cdt))
+        parts.append(_scaled(
+            jnp.take(params["embed"], tokens, axis=0).astype(cdt),
+            cfg.embed_scale))
     x = jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
     # constrain the embedding output immediately: the vocab-sharded
     # lookup otherwise materializes a FULL (B,S,d) activation + its
@@ -374,6 +385,7 @@ def forward(cfg: ModelConfig, params: Params, tokens=None, *,
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     if logits_slice:
         x = x[:, -1:]
+    x = _scaled(x, cfg.logit_scale)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     logits = (x @ head.astype(cdt)).astype(jnp.float32)
     return logits, new_caches, aux_total

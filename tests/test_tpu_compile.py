@@ -126,3 +126,38 @@ def test_engine_rows_programs_alias_their_cache_on_v5e(one_chip, program):
             < V5E_HBM_BYTES)
     whole = r"bf16\[32,8,1280,32,96\]\{[^}]*\} copy\("
     assert not re.search(whole, compiled.as_text())
+
+
+@pytest.mark.parametrize("program", ["_decode", "_prefill"])
+def test_minicpm_rows_programs_fit_v5e(one_chip, program):
+    """minicpm-2b's decode step and prefill over 16 rows x 1280 tokens in
+    bf16 (7.55 GB of cache beside 5.45 GB of weights): the cache is
+    written in place, everything fits the chip, and the tied head reads
+    the embedding where it lies (no copy or transpose of the 122,753 x
+    2304 table)."""
+    from repro.serving import engine
+
+    cfg = dataclasses.replace(get_config("minicpm-2b"), param_dtype="bfloat16")
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: _spec(one_chip, s.shape, s.dtype), tree)
+    params = on_chip(transformer.param_specs(cfg))
+    assert "lm_head" not in params
+    caches = on_chip(jax.eval_shape(
+        lambda: transformer.rows_cache(cfg, 16, 1280)))
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(caches))
+    if program == "_decode":
+        args = (_spec(one_chip, (16,), jnp.int32), caches)
+    else:
+        args = (_spec(one_chip, (1, 1024), jnp.int32), 1280, caches,
+                _spec(one_chip, (), jnp.int32))
+    compiled = getattr(engine, program).lower(cfg, params, *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= cache_bytes - 2**20   # lengths aside
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes
+            < V5E_HBM_BYTES)
+    text = compiled.as_text()
+    assert not re.search(r"bf16\[40,16,1280,36,64\]\{[^}]*\} copy\(", text)
+    assert not re.search(r"bf16\[(122753,2304|2304,122753)\]\{[^}]*\} "
+                         r"(copy|transpose)\(", text)
